@@ -44,6 +44,9 @@ def _measure(devices: int, rows: int, kind: str) -> str:
         print('TIME=', min(ts), 'COUNT=', float(np.asarray(out.columns['count_rows'])[0]))
     """
     env = dict(os.environ)
+    # the shards are host CPU devices: the child never opens the accelerator,
+    # which a parent that ran other figures (benchmarks.run) already holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src") + ":" + REPO
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

@@ -23,15 +23,17 @@ Part 2 — multi-stage (MLUdf host-boundary) plan, the StageGraph payoff:
   pump    — same, flushed by the background pump (prep.serve(
             max_latency_ms=...)) with per-request p50/p99 latency.
 
-Part 3 — cold-process A/B, the artifact-store payoff:
+Part 3 — restart A/B, the artifact-store payoff:
 
-  each leg spawns a FRESH interpreter (``--cold-child``) that connects,
-  prepares, serves, and submits a fixed bucket ladder, timing prepare +
-  first-flush — the cold-start cost a restarted serving process pays.
-  ``nocache`` runs without a cache_dir; ``cold`` populates a fresh one
-  (optimizer output + AOT-exported stage programs land on disk); ``warm``
-  reuses it: the optimizer is skipped and every bucket deserializes with
-  zero new XLA traces.
+  each leg clears the compiled-plan cache and opens a fresh session that
+  connects, prepares, serves, and submits a fixed bucket ladder, timing
+  prepare + first-flush — the cold-start cost a restarted server pays. The
+  legs run in the benchmark's own process, the one that holds the
+  accelerator. ``nocache`` runs without a cache_dir; ``cold`` populates a
+  fresh one (optimizer output + AOT-exported stage programs land on disk);
+  ``warm`` reuses it: the optimizer is skipped and every bucket deserializes
+  with zero new XLA traces. (The cross-process round trip itself is covered
+  by tests/test_artifact_store.py on the CPU.)
 
 Part 4 — mixed workload, the pipelined-scheduler payoff:
 
@@ -82,11 +84,11 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import tempfile
 import threading
 import time
+from typing import Optional
 
 import numpy as np
 
@@ -253,59 +255,48 @@ def run_multistage(db, sql, batches, total_rows):
     }
 
 
-def _cold_child(pipe_path: str, cache_dir: str) -> None:
-    """One fresh-interpreter serving cold start (invoked via --cold-child).
-
-    Times connect+prepare and the first flush of a fixed bucket ladder, then
-    prints one json line the parent collects. ``cache_dir`` empty -> no
-    artifact store (the baseline).
-    """
-    from repro.ml.pipeline import load_pipeline
-
-    pipe = load_pipeline(pipe_path)
+def _restart_leg(pipe, cache_dir: Optional[str]) -> dict:
+    """One serving restart, in this process: the compiled-plan cache is
+    cleared and a fresh session connects, prepares, serves, and submits a
+    fixed bucket ladder, timing connect+prepare and the first flush — the
+    cold-start cost a restarted server pays. ``cache_dir=None`` runs without
+    an artifact store (the baseline)."""
+    clear_plan_cache()
     ds = make_hospital(4096, seed=0)
     batches = [make_hospital(n, seed=50 + i).tables["patients"]
                for i, n in enumerate((120, 250, 500, 1000))]
     t0 = time.perf_counter()
-    db = raven.connect(ds.tables, stats="auto", cache_dir=cache_dir or None)
-    db.register_model("m", pipe)
-    prep = db.sql(
-        "SELECT * FROM PREDICT(model='m', data=patients) AS p "
-        "WHERE score >= :t"
-    ).prepare(transform="sql", params={"t": 0.6}).serve("hot")
-    t_prepare = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for b in batches:
-        prep.submit(b)
-        db.flush()  # flush per submit: each size lands its own bucket
-    t_first = time.perf_counter() - t0
-    s = db.cache_stats()
-    print(json.dumps({
+    db = raven.connect(ds.tables, stats="auto",
+                       options=raven.ConnectOptions(cache_dir=cache_dir))
+    try:
+        db.register_model("m", pipe)
+        prep = db.sql(
+            "SELECT * FROM PREDICT(model='m', data=patients) AS p "
+            "WHERE score >= :t"
+        ).prepare(transform="sql", params={"t": 0.6}).serve("hot")
+        t_prepare = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        for b in batches:
+            prep.submit(b)
+            db.flush()  # flush per submit: each size lands its own bucket
+        t_first = time.perf_counter() - t0
+        s = db.cache_stats()
+    finally:
+        db.close()  # drains the artifact store's background writes
+    return {
         "prepare_s": t_prepare, "first_flush_s": t_first,
         "traces": s["traces"], "disk_hits": s["disk_hits"],
-    }))
+    }
 
 
-def run_cold(pipe_path: str) -> dict:
-    """Cold-process A/B: fresh interpreter with cache off / cold / warm."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + "."
-
-    def leg(cache_dir: str) -> dict:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--cold-child",
-             pipe_path, cache_dir],
-            capture_output=True, text=True, timeout=600, env=env,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        if proc.returncode != 0:
-            raise RuntimeError(f"cold child failed:\n{proc.stderr[-2000:]}")
-        return json.loads(proc.stdout.strip().splitlines()[-1])
-
+def run_cold(pipe) -> dict:
+    """Restart A/B: cache off / cold / warm, each a fresh session over a
+    cleared plan cache. The legs run in this process — the one that holds
+    the accelerator — so no child ever needs the chip."""
     with tempfile.TemporaryDirectory() as cache:
-        nocache = leg("")
-        cold = leg(cache)    # populates the store
-        warm = leg(cache)    # the restarted-process payoff
+        nocache = _restart_leg(pipe, None)
+        cold = _restart_leg(pipe, cache)    # populates the store
+        warm = _restart_leg(pipe, cache)    # the restarted-server payoff
 
     print("serve_query_cold,variant,prepare_s,first_flush_s,traces,disk_hits")
     for name, r in (("nocache", nocache), ("cold", cold), ("warm", warm)):
@@ -315,8 +306,8 @@ def run_cold(pipe_path: str) -> dict:
     print(f"serve_query_cold,speedup,warm vs nocache = "
           f"{total(nocache) / total(warm):.1f}x "
           f"(traces {nocache['traces']} -> {warm['traces']})")
-    assert warm["traces"] == 0, "warm cold-start must not re-trace"
-    assert warm["disk_hits"] > 0, "warm cold-start must hit the disk tier"
+    assert warm["traces"] == 0, "warm restart must not re-trace"
+    assert warm["disk_hits"] > 0, "warm restart must hit the disk tier"
     return {
         "cold_nocache_s": total(nocache), "cold_cold_s": total(cold),
         "cold_warm_s": total(warm),
@@ -1012,13 +1003,8 @@ def run(quick: bool = False):
     # where the old exact-shape path churned and re-traced
     rows.update(run_multistage(db, sql, batches, total_rows))
 
-    # part 3: cold-process A/B through the artifact store
-    from repro.ml.pipeline import save_pipeline
-
-    with tempfile.TemporaryDirectory() as d:
-        pipe_path = os.path.join(d, "pipe.npz")
-        save_pipeline(pipe, pipe_path)
-        rows.update(run_cold(pipe_path))
+    # part 3: restart A/B through the artifact store
+    rows.update(run_cold(pipe))
 
     # part 4: mixed workload, serial vs pipelined scheduling
     rows.update(run_mixed(db, sql, quick=quick))
@@ -1113,10 +1099,7 @@ def smoke() -> dict:
 
 
 if __name__ == "__main__":
-    if "--cold-child" in sys.argv:
-        i = sys.argv.index("--cold-child")
-        _cold_child(sys.argv[i + 1], sys.argv[i + 2])
-    elif "--smoke" in sys.argv:
+    if "--smoke" in sys.argv:
         _write_json(smoke(), sys.argv)
     else:
         _write_json(run(quick="--quick" in sys.argv), sys.argv)

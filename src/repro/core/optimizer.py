@@ -52,7 +52,6 @@ from repro.relational.engine import (
     Project,
     Scan,
     TensorOp,
-    walk_plan,
 )
 from repro.core.fingerprint import fingerprint
 from repro.relational.expr import (
@@ -107,10 +106,6 @@ class OptimizationReport:
     # filled when the verify mode is 'warn' or 'strict'; rendered by
     # explain()
     verification: list[str] = field(default_factory=list)
-    # relational-op runtime placement (Join / Aggregate), filled after
-    # lowering: (op label, runtime description). Reflects the process-wide
-    # RAVEN_KERNELS mode captured when the stage graph is built.
-    relational: list[tuple[str, str]] = field(default_factory=list)
 
 
 class RavenOptimizer:
@@ -196,27 +191,6 @@ class RavenOptimizer:
                 "after lowering",
             )
         report.stages = describe_segments(plan)
-        from repro.kernels.ops import kernels_enabled
-
-        kern = kernels_enabled()
-        for node in walk_plan(plan):
-            if isinstance(node, Join):
-                report.relational.append((
-                    f"Join[{node.dim_table}] on "
-                    f"{node.fact_key}={node.dim_key}",
-                    "tensor/kernel: gather_join, upstream filter mask fused"
-                    " (jnp fallback when shapes don't qualify)"
-                    if kern else
-                    "tensor/jnp: argsort+searchsorted gather",
-                ))
-            elif isinstance(node, Aggregate):
-                aggs = ", ".join(f"{n}={op}({c})" for n, op, c in node.aggs)
-                report.relational.append((
-                    f"Aggregate[{aggs}]",
-                    "tensor/kernel: segment_agg, filter folded in as mask"
-                    if kern else
-                    "tensor/jnp: masked segment_sum/min/max",
-                ))
         n_host = sum(1 for s in report.stages if s.startswith("host"))
         if n_host:
             report.notes.append(
@@ -320,6 +294,7 @@ class RavenOptimizer:
                     "MLtoDNN fused featurize kernel: "
                     + ", ".join(comp.fused)
                 )
+            _note_tree_runtimes(report, comp, opt.use_pallas)
             report.placement.append(
                 [(label, "tensor") for label, _ in part.split.placement]
             )
@@ -373,6 +348,7 @@ class RavenOptimizer:
         if part.prefix is not None:
             comp, seg = part.prefix
             fused += list(comp.fused)
+            _note_tree_runtimes(report, comp, opt.use_pallas)
             plan = TensorOp(
                 plan, tensor_wrap(comp, seg, "prefix"),
                 list(seg.out_cols), consumes=tuple(seg.consumes),
@@ -385,6 +361,7 @@ class RavenOptimizer:
         if part.suffix is not None:
             comp, seg = part.suffix
             fused += list(comp.fused)
+            _note_tree_runtimes(report, comp, opt.use_pallas)
             plan = TensorOp(
                 plan, tensor_wrap(comp, seg, "suffix"),
                 list(seg.out_cols), consumes=tuple(seg.consumes),
@@ -448,6 +425,16 @@ class RavenOptimizer:
             [(_pipeline_node_label(n), "sql") for n in p.pipeline.nodes]
         )
         return Project(child, None, exprs)
+
+
+def _note_tree_runtimes(report: OptimizationReport, comp, use_pallas) -> None:
+    """Name, per tree-ensemble output, the strategy and runtime it got."""
+    from repro.tensor.compile import tree_runtime
+
+    for out, strat in sorted(comp.strategy.items()):
+        report.notes.append(
+            f"MLtoDNN tree ensemble {out}: {tree_runtime(strat, use_pallas)}"
+        )
 
 
 def _logical_out_cols(p: LogicalPlan) -> list[str]:

@@ -144,7 +144,7 @@ def pure_step(plan, inner: Optional[Callable[[dict], State]]) -> Callable[[dict]
         def fn(env, _plan=plan, _kern=use_kernels):
             from repro.tensor.compile import (
                 emit_join_kernel,
-                join_kernel_qualifies,
+                join_kernel_choice,
             )
 
             cols, valid, seg = inner(env)
@@ -152,7 +152,7 @@ def pure_step(plan, inner: Optional[Callable[[dict], State]]) -> Callable[[dict]
             keys = dim[_plan.dim_key]
             fk = cols[_plan.fact_key]
             ds = env.get(DIMSORT_KEY, {}).get(_plan.dim_table)
-            if _kern and join_kernel_qualifies(_plan, dim, fk, ds):
+            if _kern and join_kernel_choice(_plan, dim, fk, ds) is None:
                 brought, hit = emit_join_kernel(_plan, dim, fk, ds)
                 out = dict(cols)
                 out.update(brought)
@@ -206,7 +206,10 @@ def pure_step(plan, inner: Optional[Callable[[dict], State]]) -> Callable[[dict]
         use_kernels = kernels_enabled()
 
         def fn(env, _plan=plan, _kern=use_kernels):
-            from repro.tensor.compile import emit_aggregate_kernel
+            from repro.tensor.compile import (
+                aggregate_kernel_choice,
+                emit_aggregate_kernel,
+            )
 
             cols, valid, seg = inner(env)
             w = valid.astype(jnp.float32)
@@ -244,7 +247,7 @@ def pure_step(plan, inner: Optional[Callable[[dict], State]]) -> Callable[[dict]
             ns = slots.shape[0]
             k = env[SEG_COUNT_KEY]
             sid = jnp.where(valid, seg, 0)
-            if _kern:
+            if _kern and aggregate_kernel_choice(_plan.aggs, ns) is None:
                 out = emit_aggregate_kernel(_plan.aggs, cols, w, sid, ns)
                 return out, slots < k, slots
             counts = jax.ops.segment_sum(w, sid, num_segments=ns)

@@ -16,6 +16,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import VMEM_LIMIT_BYTES
 
 
 def _kernel(
@@ -91,7 +94,12 @@ def featurize(
         ],
         out_specs=pl.BlockSpec((block_n, Fout), lambda n: (n, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, Fout), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
+        name="featurize",
     )(
         num,
         cat,

@@ -16,7 +16,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref as _ref
-from repro.kernels.tree_gemm import tree_gemm as _tree_gemm_kernel
+from repro.kernels.tree_gemm import (
+    tree_gemm as _tree_gemm_kernel,
+    tree_gemm_block_n,
+)
 from repro.kernels.featurize import featurize as _featurize_kernel
 from repro.kernels.relational import (
     gather_join as _gather_join_kernel,
@@ -79,10 +82,11 @@ def pad_gemm_program(A, B, C, D, V, align: int = 128):
 
 @functools.partial(jax.jit, static_argnames=("base", "block_n", "use_pallas", "interpret"))
 def tree_gemm_op(
-    x, A, B, C, D, V, *, base: float, block_n: int = 256,
+    x, A, B, C, D, V, *, base: float, block_n: int | None = None,
     use_pallas: bool | None = None, interpret: bool = False,
 ):
-    """(N,F) rows → (N,) raw scores. Pads N to block_n and F to A's F."""
+    """(N,F) rows → (N,) raw scores. Pads N to block_n and F to A's F;
+    ``block_n=None`` sizes the row block from the step's VMEM need."""
     if use_pallas is None:
         use_pallas = _on_tpu()
     N, F = x.shape
@@ -90,6 +94,8 @@ def tree_gemm_op(
     if not (use_pallas or interpret):
         xp = jnp.pad(x, ((0, 0), (0, Fk - F))) if Fk > F else x
         return _ref.tree_gemm_ref(xp, A, B, C, D, V, base)
+    if block_n is None:
+        block_n = tree_gemm_block_n(N, Fk, A.shape[2], C.shape[2])
     Np = _round_up(max(N, 1), block_n)
     xp = jnp.pad(x.astype(jnp.float32), ((0, Np - N), (0, Fk - F)))
     out = _tree_gemm_kernel(
@@ -132,7 +138,7 @@ def featurize_op(
     jax.jit, static_argnames=("block_n", "use_pallas", "interpret")
 )
 def gather_join_op(
-    fk, skeys, spay, *, block_n: int = 256,
+    fk, skeys, spay, *, block_n: int | None = None,
     use_pallas: bool | None = None, interpret: bool = False,
 ):
     """Dim-table equi-join gather. fk:(N,) int32; skeys:(M,) sorted *unique*
@@ -154,11 +160,12 @@ def gather_join_op(
     static_argnames=("num_segments", "block_n", "use_pallas", "interpret"),
 )
 def segment_agg_op(
-    vals, w, sid, *, num_segments: int, block_n: int = 256,
+    vals, w, sid, *, num_segments: int, block_n: int | None = None,
     use_pallas: bool | None = None, interpret: bool = False,
 ):
     """Masked segmented aggregate. vals:(N,C) f32; w:(N,) f32 validity
     weights (the fused filter mask); sid:(N,) int32 in [0, num_segments).
+    ``block_n=None`` sizes the row block from the step's VMEM need.
     Returns ``(counts, sums, mins, maxs)`` — counts:(S,), the rest (S,C);
     mins/maxs are +inf/-inf where a segment has no valid rows (callers
     replace empties via ``counts > 0``)."""

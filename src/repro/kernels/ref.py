@@ -8,11 +8,12 @@ import jax.numpy as jnp
 def tree_gemm_ref(x, A, B, C, D, V, base: float) -> jnp.ndarray:
     """GEMM-strategy tree inference. x:(N,F); A:(T,F,I); B:(T,I); C:(T,I,L);
     D:(T,L); V:(T,L) -> (N,) raw scores."""
-    S = jnp.einsum("nf,tfi->nti", x.astype(jnp.float32), A)
+    hi = jax.lax.Precision.HIGHEST
+    S = jnp.einsum("nf,tfi->nti", x.astype(jnp.float32), A, precision=hi)
     dec = (S <= B[None]).astype(jnp.float32)
-    P = jnp.einsum("nti,til->ntl", dec, C)
+    P = jnp.einsum("nti,til->ntl", dec, C, precision=hi)
     match = (P == D[None]).astype(jnp.float32)
-    return jnp.einsum("ntl,tl->n", match, V) + base
+    return jnp.einsum("ntl,tl->n", match, V, precision=hi) + base
 
 
 def featurize_ref(num, cat, offset, scale, cat_values, cat_segments):

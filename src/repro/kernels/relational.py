@@ -24,10 +24,22 @@ stacked in), min/max are masked broadcast reductions. The filter mask ``w``
 is folded in as the weight — filtered rows contribute exactly zero and are
 never materialized.
 
-Both kernels use the same treatment as the PR 6 ``featurize`` kernel: rows
+Both kernels use the same treatment as the ``featurize`` kernel: rows
 padded to a multiple of ``block_n`` with provably inert values and cropped
 back, zero-width operands widened to one inert column, ``interpret=True``
-for CPU correctness tests.
+for CPU correctness tests. Their matmuls run at ``Precision.HIGHEST`` (the
+TPU's default f32 matmul rounds operands to bf16, which would break the
+bitwise gather and the f32 sums).
+
+The gather-join keeps the whole padded payload resident in VMEM, so it can
+only hold dimension tables up to a size: :func:`gather_join_block_n` picks
+the row block from the step's VMEM need and returns ``None`` when no block
+fits, and the Join lowering then keeps the jnp gather (see
+``repro.tensor.compile.join_kernel_choice``). The segmented aggregate keeps
+its ``(segments × columns)`` accumulators resident the same way:
+:func:`segment_agg_block_n` bounds the segment count, and the Aggregate
+lowering keeps the jnp segment ops beyond it
+(``repro.tensor.compile.aggregate_kernel_choice``).
 """
 from __future__ import annotations
 
@@ -36,6 +48,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
+
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _round_up(x: int, m: int) -> int:
@@ -55,9 +72,34 @@ def _gather_join_body(fk_ref, keys_ref, pay_ref, out_ref, hit_ref, *, m_real):
     col = jax.lax.broadcasted_iota(jnp.int32, onehot.shape, 1)
     onehot_f = jnp.where(onehot & (col < m_real), 1.0, 0.0).astype(jnp.float32)
     out_ref[...] = jnp.dot(
-        onehot_f, pay_ref[...], preferred_element_type=jnp.float32
+        onehot_f, pay_ref[...], precision=_HI,
+        preferred_element_type=jnp.float32,
     )
     hit_ref[...] = jnp.sum(onehot_f, axis=1, keepdims=True)
+
+
+def gather_join_vmem_bytes(block_n: int, M: int, P: int) -> int:
+    """VMEM one gather-join step holds: double-buffered blocks (the resident
+    ``(Mp, Pp)`` payload and ``(1, Mp)`` keys, the row blocks; width-1
+    columns occupy whole 128-lane tiles) plus the ``(block_n, Mp)`` match,
+    column-index and one-hot temporaries."""
+    Mp = _round_up(max(M, 1), 128)
+    Pp = _round_up(max(P, 1), 128)
+    blocks = Mp * Pp + 8 * Mp + block_n * (Pp + 2 * 128)
+    return 4 * (2 * blocks + 3 * block_n * Mp)
+
+
+def gather_join_block_n(M: int, P: int):
+    """Largest power-of-two row block in [8, 256] whose step fits the VMEM
+    budget for an ``M``-row, ``P``-column dimension payload, or ``None``
+    when even an 8-row block does not: the kernel cannot hold that
+    dimension table."""
+    bn = 256
+    while bn >= 8:
+        if gather_join_vmem_bytes(bn, M, P) <= VMEM_BUDGET_BYTES:
+            return bn
+        bn //= 2
+    return None
 
 
 def gather_join(
@@ -65,12 +107,13 @@ def gather_join(
     skeys: jnp.ndarray,
     spay: jnp.ndarray,
     *,
-    block_n: int = 256,
+    block_n: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """fk:(N,) int32 fact keys; skeys:(M,) int32 *unique* dim keys;
     spay:(M,P) f32 payload aligned to ``skeys``. Returns ``(out, hit)``:
     out:(N,P) f32 gathered payload (zero on miss), hit:(N,) bool.
+    ``block_n=None`` sizes the row block with :func:`gather_join_block_n`.
 
     Inert padding proof: extra rows only extend the grid and are cropped;
     extra key columns are masked by the in-kernel ``col < M`` guard (their
@@ -79,6 +122,12 @@ def gather_join(
     """
     N = fk.shape[0]
     M, P = spay.shape
+    if block_n is None:
+        block_n = gather_join_block_n(M, P)
+        if block_n is None:
+            raise ValueError(
+                f"gather_join cannot hold a {M}x{P} dimension payload in VMEM"
+            )
     Mp = _round_up(max(M, 1), 128)
     Pp = _round_up(max(P, 1), 128)
     Np = _round_up(max(N, 1), block_n)
@@ -101,7 +150,12 @@ def gather_join(
             jax.ShapeDtypeStruct((Np, Pp), jnp.float32),
             jax.ShapeDtypeStruct((Np, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
+        name="gather_join",
     )(fk.reshape(-1, 1), keys.reshape(1, -1), pay)
     return out[:N, :P], hit[:N, 0] > 0
 
@@ -129,7 +183,7 @@ def _segment_agg_body(
     # sums and counts in one MXU pass: contract the row axis
     sum_ref[...] += jax.lax.dot_general(
         onehot_f, vals * w, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=_HI, preferred_element_type=jnp.float32,
     )
     mask = onehot & (w > 0)  # (BN, Sp): row feeds segment AND survived filter
     for j in range(n_cols):
@@ -140,13 +194,49 @@ def _segment_agg_body(
         max_ref[j : j + 1, :] = jnp.maximum(max_ref[j : j + 1, :], mx[None, :])
 
 
+def segment_agg_vmem_bytes(block_n: int, S: int, C: int) -> int:
+    """VMEM one segment-agg step holds for ``S`` segments and ``C`` source
+    columns: double-buffered row blocks (width-1 columns occupy whole
+    128-lane tiles), the resident ``(Sp, Cp)`` sums — double-buffered, plus
+    the matmul result and its reload — and ``(C8, Sp)`` extrema, and the
+    ``(block_n, Sp)`` one-hot and mask temporaries."""
+    Sp = _round_up(max(S, 1), 128)
+    Cp = _round_up(C + 1, 128)
+    C8 = _round_up(C + 1, 8)
+    blocks = block_n * (Cp + 2 * 128)
+    return 4 * (2 * blocks + 4 * Sp * Cp + 4 * C8 * Sp + 2 * block_n * Sp)
+
+
+def segment_agg_block_n(S: int, C: int):
+    """Largest power-of-two row block in [8, 256] whose step fits the VMEM
+    budget for ``S`` segments over ``C`` source columns, or ``None`` when
+    even an 8-row block does not: the kernel cannot hold that many
+    segments."""
+    bn = 256
+    while bn >= 8:
+        if segment_agg_vmem_bytes(bn, S, C) <= VMEM_BUDGET_BYTES:
+            return bn
+        bn //= 2
+    return None
+
+
+def segment_agg_max_segments(C: int) -> int:
+    """The largest power-of-two segment count (the serving path's slot
+    buckets are powers of two) the kernel holds over ``C`` source
+    columns."""
+    s = 1
+    while segment_agg_block_n(2 * s, C) is not None:
+        s *= 2
+    return s
+
+
 def segment_agg(
     vals: jnp.ndarray,
     w: jnp.ndarray,
     sid: jnp.ndarray,
     *,
     num_segments: int,
-    block_n: int = 256,
+    block_n: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """vals:(N,C) f32 aggregate source columns; w:(N,) f32 validity weights
@@ -155,6 +245,7 @@ def segment_agg(
     counts:(S,) weighted row counts; sums:(S,C) masked segment sums;
     mins/maxs:(S,C) masked extrema (+inf/-inf where a segment has no valid
     rows — callers replace empties via ``counts > 0``).
+    ``block_n=None`` sizes the row block with :func:`segment_agg_block_n`.
 
     Inert padding proof: padded rows carry ``w=0, sid=0, vals=0`` — they add
     ``0 * 0`` to segment 0's sums and are excluded from min/max by the
@@ -163,6 +254,12 @@ def segment_agg(
     """
     N, C = vals.shape
     S = num_segments
+    if block_n is None:
+        block_n = segment_agg_block_n(S, C)
+        if block_n is None:
+            raise ValueError(
+                f"segment_agg cannot hold {S} segments x {C} columns in VMEM"
+            )
     Np = _round_up(max(N, 1), block_n)
     Sp = _round_up(max(S, 1), 128)
     Cp = _round_up(C + 1, 128)  # col 0 = weight (counts ride the same matmul)
@@ -191,7 +288,12 @@ def segment_agg(
             jax.ShapeDtypeStruct((C8, Sp), jnp.float32),
             jax.ShapeDtypeStruct((C8, Sp), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
+        name="segment_agg",
     )(stacked, wp.reshape(-1, 1), sidp.reshape(-1, 1))
     counts = sums[:S, 0]
     return counts, sums[:S, 1 : C + 1], mins[1 : C + 1, :S].T, maxs[1 : C + 1, :S].T

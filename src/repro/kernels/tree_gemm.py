@@ -1,9 +1,9 @@
 """Pallas TPU kernel: GEMM-strategy tree-ensemble inference.
 
-The paper's MLtoDNN hotspot, rethought for the MXU (DESIGN.md §2): each
-(batch-block, tree) grid step runs the fused chain
+The paper's MLtoDNN hotspot, rethought for the MXU: each (batch-block, tree)
+grid step runs the fused chain
 
-    S = X·A  →  D = (S ≤ B)  →  P = D·C  →  match = (P == Dcount)  →  y += match·V
+    S = X·A  →  D = (S ≤ B)  →  P = D·C  →  match = (P == Dcount)  →  y += Σ match·V
 
 entirely in VMEM, with the two contractions on the MXU. Trees accumulate into
 the output block across the innermost grid dimension (revisited output block;
@@ -12,8 +12,15 @@ init at t == 0) — no HBM round-trips between trees.
 Tiling: rows are tiled by ``block_n``; F/I/L are MXU-aligned by padding in
 ``repro.kernels.ops`` (zero feature columns, +inf thresholds, zero path
 columns and Dcount = -1 are all provably inert — see ops.pad_gemm_program).
-VMEM footprint per step ≈ 4·(block_n·F + F·I + I·L + block_n·(I+L)) bytes;
-callers pick block_n so this stays under ~12 MB of the 16 MB VMEM budget.
+The per-tree vectors B, D and V travel as ``(T, 1, I|L)`` so each grid step's
+block ``(1, 1, I|L)`` keeps its last two dimensions TPU-tileable. Callers
+size ``block_n`` with :func:`tree_gemm_block_n`, which budgets the step's
+VMEM (:func:`tree_gemm_vmem_bytes`) under ``repro.kernels.VMEM_BUDGET_BYTES``.
+
+Both contractions run at ``Precision.HIGHEST``: at the TPU's default
+precision an f32 matmul rounds its operands to bf16, which would round the
+features before the ``S <= B`` threshold compare and the leaf values before
+they are summed.
 """
 from __future__ import annotations
 
@@ -22,6 +29,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def tree_gemm_vmem_bytes(block_n: int, F: int, I: int, L: int) -> int:
+    """VMEM one grid step holds: double-buffered input/output blocks (the
+    ``(1, I|L)`` rows and the ``(block_n, 1)`` output occupy whole (8, 128)
+    tiles) plus the f32 temporaries S, D, P, match and match·V."""
+    blocks = (
+        block_n * F + F * I + I * L  # x, A, C
+        + 8 * I + 2 * 8 * L  # B, D, V
+        + block_n * 128  # output column
+    )
+    temps = block_n * (2 * I + 3 * L)
+    return 4 * (2 * blocks + temps)
+
+
+def tree_gemm_block_n(n_rows: int, F: int, I: int, L: int) -> int:
+    """Largest power-of-two row block in [8, 512] whose step fits the VMEM
+    budget, and no larger than the row count's own power-of-two bucket (so a
+    small batch is not padded up to a full block)."""
+    cap = 1 << max(3, (max(n_rows, 1) - 1).bit_length())
+    bn = 512
+    while bn > 8 and (bn > cap or tree_gemm_vmem_bytes(bn, F, I, L) > VMEM_BUDGET_BYTES):
+        bn //= 2
+    return bn
 
 
 def _kernel(x_ref, a_ref, b_ref, c_ref, d_ref, v_ref, o_ref, *, base: float):
@@ -31,16 +67,15 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, d_ref, v_ref, o_ref, *, base: float):
     def _init():
         o_ref[...] = jnp.full_like(o_ref, base)
 
-    x = x_ref[...]  # (BN, F)
-    a = a_ref[0]  # (F, I)
-    s = jnp.dot(x, a, preferred_element_type=jnp.float32)  # MXU
-    dec = (s <= b_ref[0][None, :]).astype(jnp.float32)  # (BN, I)
-    p = jnp.dot(dec, c_ref[0], preferred_element_type=jnp.float32)  # MXU
-    match = (p == d_ref[0][None, :]).astype(jnp.float32)  # (BN, L)
-    part = jnp.dot(
-        match, v_ref[0][:, None], preferred_element_type=jnp.float32
-    )  # (BN, 1)
-    o_ref[...] += part
+    s = jnp.dot(
+        x_ref[...], a_ref[0], precision=_HI, preferred_element_type=jnp.float32
+    )  # (BN, I) on the MXU
+    dec = (s <= b_ref[0]).astype(jnp.float32)  # (BN, I) vs (1, I)
+    p = jnp.dot(
+        dec, c_ref[0], precision=_HI, preferred_element_type=jnp.float32
+    )  # (BN, L) on the MXU
+    match = (p == d_ref[0]).astype(jnp.float32)  # (BN, L); ≤ one leaf per row
+    o_ref[...] += jnp.sum(match * v_ref[0], axis=1, keepdims=True)
 
 
 def tree_gemm(
@@ -68,20 +103,25 @@ def tree_gemm(
         in_specs=[
             pl.BlockSpec((block_n, F), lambda n, t: (n, 0)),
             pl.BlockSpec((1, F, I), lambda n, t: (t, 0, 0)),
-            pl.BlockSpec((1, I), lambda n, t: (t, 0)),
+            pl.BlockSpec((1, 1, I), lambda n, t: (t, 0, 0)),
             pl.BlockSpec((1, I, L), lambda n, t: (t, 0, 0)),
-            pl.BlockSpec((1, L), lambda n, t: (t, 0)),
-            pl.BlockSpec((1, L), lambda n, t: (t, 0)),
+            pl.BlockSpec((1, 1, L), lambda n, t: (t, 0, 0)),
+            pl.BlockSpec((1, 1, L), lambda n, t: (t, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, 1), lambda n, t: (n, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
         interpret=interpret,
+        name="tree_gemm",
     )(
         x.astype(jnp.float32),
         A.astype(jnp.float32),
-        B.astype(jnp.float32),
+        B.astype(jnp.float32).reshape(T, 1, I),
         C.astype(jnp.float32),
-        D.astype(jnp.float32),
-        V.astype(jnp.float32),
+        D.astype(jnp.float32).reshape(T, 1, L),
+        V.astype(jnp.float32).reshape(T, 1, L),
     )
     return out[:, 0]

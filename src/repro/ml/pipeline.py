@@ -216,6 +216,22 @@ def run_pipeline(
     return {o: vals[o] for o in pipeline.outputs}
 
 
+def prediction_mismatch(a: np.ndarray, b: np.ndarray) -> float:
+    """Fraction of predictions on which two runs of a model disagree: exact
+    inequality for integer/bool labels, ``|a - b| > 1e-4`` for scores. The
+    paper itself reports MLtoSQL/MLtoDNN flipping 0.006–0.3% of predictions
+    (f32 vs f64 thresholds), so callers accept a small fraction."""
+    a = np.asarray(a).reshape(-1)
+    b = np.asarray(b).reshape(-1)
+    if a.shape != b.shape:
+        raise ValueError(f"shape mismatch: {a.shape} vs {b.shape}")
+    if a.size == 0:
+        return 0.0
+    if a.dtype.kind in "iub":
+        return float((a != b).mean())
+    return float((np.abs(a - b) > 1e-4).mean())
+
+
 # ---------------------------------------------------------------------------
 # Coverage/frontier analysis: split a partially-supported pipeline
 # ---------------------------------------------------------------------------

@@ -82,8 +82,6 @@ def stack_shapes(layer_shapes: dict, n: int) -> dict:
 def embed_lookup(embed: jnp.ndarray, tokens: jnp.ndarray, mesh) -> jnp.ndarray:
     if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
         return jnp.take(embed, tokens, axis=0)
-    from jax.experimental.shard_map import shard_map
-
     ax = fsdp_axes(mesh)
     # batch stays replicated when it doesn't divide the data axes (e.g. the
     # B=1 long_500k decode cells) — vocab sharding over `model` still applies.
@@ -106,12 +104,12 @@ def embed_lookup(embed: jnp.ndarray, tokens: jnp.ndarray, mesh) -> jnp.ndarray:
         out = jnp.where(ok[..., None], out, jnp.zeros((), e.dtype))
         return jax.lax.psum(out, "model")
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", None), P(b_ax, None)),
         out_specs=P(b_ax, None, None),
-        check_rep=False,
+        check_vma=False,
     )(embed, tokens)
 
 

@@ -200,7 +200,7 @@ def clear_plan_cache() -> None:
 # bounded. Entries carry a zero-length "unique" marker array when the keys
 # are duplicate-free: its *presence in the pytree structure* is what lets
 # the traced Join step decide at trace time that the one-hot-matmul kernel
-# gather is exact (see tensor.compile.join_kernel_qualifies).
+# gather is exact (see tensor.compile.join_kernel_choice).
 
 _DIMSORT_CACHE: dict[tuple, dict[str, jnp.ndarray]] = {}
 _DIMSORT_CAPACITY = 128
@@ -351,6 +351,19 @@ class CompiledPlan:
             env[DIMSORT_KEY] = ds
         return env
 
+    def lower_entry(
+        self,
+        database: dict[str, dict[str, jnp.ndarray]],
+        params: Optional[dict[str, Any]] = None,
+    ):
+        """Lower the entry stage's program for these inputs without running
+        it or counting a trace; ``.as_text()`` shows what the program holds,
+        e.g. the Pallas kernels (``tpu_custom_call``, ``kernel_name``)."""
+        stage = self.graph.stages[0]
+        if stage.kind != "pure":
+            raise ValueError("the entry stage is a host boundary")
+        return jax.jit(stage.fn).lower(self._env(database, None, params, None))
+
     def run(
         self,
         database: dict[str, dict[str, jnp.ndarray]],
@@ -426,7 +439,8 @@ class _StageRunner:
     disk.
 
     On accelerator backends (or under ``RAVEN_DONATE=1``) a call carrying a
-    non-empty ``donate`` set runs through a second jit specialization whose
+    non-empty ``donate`` set to a stage without an Aggregate (whose outputs
+    could alias a row buffer) runs through a second jit specialization whose
     first argument — the single-use serving inputs: donated fact tables,
     the row-validity/segment vectors, the ``__mid__`` pseudo-table — is
     donated to XLA, letting the compiler alias the padded entry buffers
@@ -452,11 +466,14 @@ class _StageRunner:
 
         self.jitted = jax.jit(traced)
         self._jitted_donating: Optional[Callable] = None  # built on demand
+        # an aggregate folds the rows away: no output can alias a donated
+        # row buffer, so donating would only earn XLA's "not usable" warning
+        self._aliasable = not any(isinstance(op, Aggregate) for op in stage.ops)
         # env digest -> deserialized exported call, or None (= run live)
         self._known: dict[str, Optional[Callable]] = {}
 
     def _run_live(self, env, donate: frozenset):
-        if not donate or not donation_enabled():
+        if not donate or not self._aliasable or not donation_enabled():
             return self.jitted(env)
         if self._jitted_donating is None:
             def traced2(volatile, resident, _fn=self.stage.fn,
@@ -623,7 +640,6 @@ def compile_plan_sharded(
     become partial-per-shard + psum.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     graph = build_stage_graph(plan)
     assert len(graph.stages) == 1 and graph.is_pure, (
@@ -658,9 +674,9 @@ def compile_plan_sharded(
             if has_agg
             else ({k: P(axis) for k in _out_cols(plan)}, P(axis))
         )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         cols, valid = jax.jit(sharded)(env)
         return Table(columns=cols, valid=valid)

@@ -117,7 +117,10 @@ def connect(
     results. The store is installed process-wide (the compiled-plan cache it
     backs is process-wide too); the most recent ``connect`` wins.
     ``cache_max_bytes`` bounds the cache dir by total size (oldest entries
-    evicted first) on top of the store's entry-count cap.
+    evicted first) on top of the store's entry-count cap. Independently of
+    ``cache_dir``, ``connect`` points JAX's own persistent compilation cache
+    at ``$JAX_COMPILATION_CACHE_DIR`` or, unset, at the checkout's
+    ``.jax_cache`` (see :mod:`repro.compile_cache`).
 
     ``verify`` sets the session-wide plan-verification mode: ``"off"`` (the
     default), ``"warn"`` (verifier violations surface as
@@ -129,6 +132,9 @@ def connect(
     which plan is produced, only whether it is checked, so it is excluded
     from every plan fingerprint and cache key.
     """
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     return Session(
         tables, stats, partition_cols=partition_cols,
         strategy=strategy, options=options, cache_dir=cache_dir,
@@ -795,7 +801,7 @@ class PreparedQuery:
                     else:
                         for label, r in nodes:
                             lines.append(f"  {r:<16} {label}")
-        relational = getattr(self.report, "relational", [])
+        relational = self._relational_placement()
         if relational:
             lines.append("-- runtime placement (relational ops) " + "-" * 18)
             for label, r in relational:
@@ -827,6 +833,66 @@ class PreparedQuery:
         for st in graph.stages:
             lines.append(st.describe())
         return "\n".join(lines)
+
+    def _relational_placement(self) -> list[tuple[str, str]]:
+        """Where each Join / Aggregate runs, decided the way the traced
+        stage decides it: the ``RAVEN_KERNELS`` mode, and for a Join the
+        gather-join qualification over the session's own tables (dtypes as
+        they land on the device, baked key uniqueness, VMEM fit), so a Join
+        the kernel cannot hold shows its jnp fallback and the reason. An
+        Aggregate's slot count is only known per coalesced group, so its
+        line names the largest group the kernel holds."""
+        import jax
+
+        from repro.kernels.ops import kernels_enabled
+        from repro.relational.engine import Aggregate, Join, dimsort_entry
+        from repro.tensor.compile import (
+            aggregate_kernel_max_segments,
+            join_kernel_choice,
+        )
+
+        tables = self.query.session.tables
+        kern = kernels_enabled()
+
+        def on_device(col):
+            return jax.ShapeDtypeStruct(
+                col.shape, jax.dtypes.canonicalize_dtype(col.dtype)
+            )
+
+        out: list[tuple[str, str]] = []
+        for node in walk_plan(self.plan):
+            if isinstance(node, Join):
+                label = (
+                    f"Join[{node.dim_table}] on {node.fact_key}={node.dim_key}"
+                )
+                if not kern:
+                    out.append((label, "tensor/jnp: argsort+searchsorted gather"
+                                " (RAVEN_KERNELS=off)"))
+                    continue
+                dim = tables[node.dim_table]
+                fk = next(
+                    t[node.fact_key] for t in tables.values()
+                    if node.fact_key in t
+                )
+                why = join_kernel_choice(
+                    node, {c: on_device(v) for c, v in dim.items()},
+                    on_device(fk), dimsort_entry(dim[node.dim_key]),
+                )
+                out.append((label, (
+                    "tensor/kernel: gather_join, upstream filter mask fused"
+                    if why is None else
+                    f"tensor/jnp: argsort+searchsorted gather ({why})"
+                )))
+            elif isinstance(node, Aggregate):
+                aggs = ", ".join(f"{n}={op}({c})" for n, op, c in node.aggs)
+                out.append((f"Aggregate[{aggs}]", (
+                    "tensor/kernel: segment_agg, filter folded in as mask"
+                    f" (groups of more than "
+                    f"{aggregate_kernel_max_segments(node.aggs)} requests: "
+                    "jnp masked segment ops, VMEM budget)"
+                    if kern else "tensor/jnp: masked segment_sum/min/max"
+                )))
+        return out
 
     def __repr__(self) -> str:
         served = f", served as '{self._serve_name}'" if self._serve_name else ""

@@ -8,15 +8,19 @@ XLA fuses — the "DNN runtime" execution of the model.
 
 On TPU the tree-GEMM and featurize steps dispatch to the Pallas kernels in
 :mod:`repro.kernels`; on CPU they run the pure-jnp oracles (same math).
+Every f32 contraction runs at ``Precision.HIGHEST``: the TPU's default f32
+matmul rounds its operands to bf16.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable
+from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ops import _on_tpu
 from repro.ml.pipeline import TrainedPipeline
 from repro.ml.trees import TreeEnsemble
 from repro.tensor.tree2tensor import (
@@ -191,19 +195,32 @@ def _choose_tree_strategy(ens: TreeEnsemble) -> str:
     Heuristic mirrors Hummingbird — and like Hummingbird's, it is
     hardware-specific: the GEMM strategy exists to feed matrix units
     (MXU/TensorCore); on a CPU backend its O(F·I + I·L) dense work loses to
-    O(depth) gather-stepping by ~100x (measured, EXPERIMENTS.md §Perf), so
-    CPU always picks traversal. The paper's §5.2 point — don't hard-code
-    the crossover, learn it per hardware — is enforced by the strategy
-    corpus measuring on the live backend either way.
+    O(depth) gather-stepping, so CPU always picks traversal. The paper's
+    §5.2 point — don't hard-code the crossover, learn it per hardware — is
+    enforced by the strategy corpus measuring on the live backend either way.
     """
-    import jax
-
-    if jax.default_backend() != "tpu":
+    if not _on_tpu():
         return "traversal"
     slices = ens.tree_slices()
     max_nodes = max(sl.stop - sl.start for sl in slices)
     max_internal = (max_nodes + 1) // 2
     return "gemm" if max_internal <= 128 else "traversal"
+
+
+def tree_kernel_enabled(use_pallas: Optional[bool]) -> bool:
+    """Does a GEMM-strategy ensemble run the ``tree_gemm`` Pallas kernel?
+    ``use_pallas=None`` means yes on TPU, as for featurize and the
+    relational kernels."""
+    return bool(use_pallas) or (use_pallas is None and _on_tpu())
+
+
+def tree_runtime(strategy: str, use_pallas: Optional[bool]) -> str:
+    """How a tree ensemble compiled with ``strategy`` runs (for EXPLAIN)."""
+    if strategy == "traversal":
+        return "traversal (XLA gathers)"
+    if tree_kernel_enabled(use_pallas):
+        return "gemm (tree_gemm kernel)"
+    return "gemm (XLA einsum)"
 
 
 def compile_pipeline_tensor(
@@ -317,7 +334,7 @@ def compile_pipeline_tensor(
             elif kind in ("gemm", "traversal"):
                 X = vals[node.inputs[0]].astype(jnp.float32)
                 if kind == "gemm":
-                    if use_pallas:
+                    if tree_kernel_enabled(use_pallas):
                         from repro.kernels.ops import pad_gemm_program, tree_gemm_op
 
                         A, B, C, D, V = pad_gemm_program(
@@ -340,7 +357,8 @@ def compile_pipeline_tensor(
             elif kind == "linear":
                 X = vals[node.inputs[0]].astype(jnp.float32)
                 w = jnp.asarray(np.asarray(a["weights"], np.float32))
-                z = X @ w + jnp.float32(a["bias"])
+                z = jnp.dot(X, w, precision=jax.lax.Precision.HIGHEST)
+                z = z + jnp.float32(a["bias"])
                 if a.get("post", "none") == "logistic":
                     z = 1.0 / (1.0 + jnp.exp(-z))
                 thr = float(a.get("decision_threshold", 0.5))
@@ -382,22 +400,36 @@ def compile_pipeline_tensor(
 # and Filter→Aggregate chains fuse without materializing filtered rows.
 
 
-def join_kernel_qualifies(plan, dim, fk, ds) -> bool:
-    """Can this Join lower to the gather-join kernel? Requires the engine's
-    baked dim-sort entry with its uniqueness marker (the one-hot matmul
-    gather needs unique dim keys), integer keys on both sides, f32 payload
-    columns, and at least one payload column to gather."""
+def join_kernel_choice(plan, dim, fk, ds) -> Optional[str]:
+    """Why this Join cannot lower to the gather-join kernel, or ``None``
+    when it can. The kernel needs the engine's baked dim-sort entry with its
+    uniqueness marker (the one-hot matmul gather needs unique dim keys),
+    integer keys on both sides, f32 payload columns, at least one payload
+    column to gather, and a payload small enough to stay resident in VMEM.
+    ``dim`` maps column names to arrays (or anything with ``dtype`` and
+    ``shape``), so EXPLAIN can ask the same question of the session tables
+    that the traced Join step asks of the stage inputs."""
+    from repro.kernels.relational import gather_join_block_n
+
     if ds is None or "unique" not in ds:
-        return False
+        return "duplicate dimension keys"
     if not plan.dim_columns:
-        return False
+        return "no payload columns"
     keys = dim[plan.dim_key]
     if not (
         jnp.issubdtype(keys.dtype, jnp.integer)
         and jnp.issubdtype(fk.dtype, jnp.integer)
     ):
-        return False
-    return all(dim[c].dtype == jnp.float32 for c in plan.dim_columns)
+        return "non-integer join keys"
+    if not all(dim[c].dtype == jnp.float32 for c in plan.dim_columns):
+        return "payload columns not all f32"
+    m = int(keys.shape[0])
+    if gather_join_block_n(m, len(plan.dim_columns)) is None:
+        return (
+            f"{m}x{len(plan.dim_columns)} payload exceeds the kernel's "
+            "VMEM budget"
+        )
+    return None
 
 
 def emit_join_kernel(plan, dim, fk, ds):
@@ -419,16 +451,42 @@ def emit_join_kernel(plan, dim, fk, ds):
     return brought, hit
 
 
+def _agg_sources(aggs) -> list[str]:
+    """The distinct value columns an Aggregate's aggs read, in order."""
+    src: list[str] = []
+    for _, op, col in aggs:
+        if op != "count" and col not in src:
+            src.append(col)
+    return src
+
+
+def aggregate_kernel_choice(aggs, num_segments: int) -> Optional[str]:
+    """Why an Aggregate over ``num_segments`` output slots cannot lower to
+    the segment-agg kernel, or ``None`` when it can: the kernel keeps every
+    segment's accumulators resident in VMEM, so it holds a bounded number
+    of segments (:func:`aggregate_kernel_max_segments`)."""
+    from repro.kernels.relational import segment_agg_block_n
+
+    if segment_agg_block_n(num_segments, len(_agg_sources(aggs))) is None:
+        return f"{num_segments} segments exceed the kernel's VMEM budget"
+    return None
+
+
+def aggregate_kernel_max_segments(aggs) -> int:
+    """The most request slots one segment-agg kernel call holds for these
+    aggs; a coalesced group with more runs the jnp segment ops."""
+    from repro.kernels.relational import segment_agg_max_segments
+
+    return segment_agg_max_segments(len(_agg_sources(aggs)))
+
+
 def emit_aggregate_kernel(aggs, cols, w, sid, num_segments):
     """Emit one masked segmented-aggregate kernel call covering every agg of
     an Aggregate op (sum/mean/count share a single one-hot matmul; min/max
     ride the same pass). ``w`` is the fused filter/validity mask."""
     from repro.kernels.ops import segment_agg_op
 
-    src: list[str] = []
-    for _, op, col in aggs:
-        if op != "count" and col not in src:
-            src.append(col)
+    src = _agg_sources(aggs)
     n = w.shape[0]
     if src:
         vals = jnp.stack([cols[c].astype(jnp.float32) for c in src], axis=1)

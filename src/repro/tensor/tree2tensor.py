@@ -1,6 +1,6 @@
 """Tree ensembles → tensor programs (two strategies, as in Hummingbird).
 
-GEMM strategy — the MXU-native one (see DESIGN.md §2): trees become three
+GEMM strategy — the MXU-native one: trees become three
 dense contractions
 
     S = X · A          (N,F)·(T,F,I) -> (N,T,I)   split-feature values
@@ -10,7 +10,8 @@ dense contractions
     y = Σ_t leaf · V   + base
 
 All shapes are padded: I (internal nodes) and L (leaves) to the ensemble max
-(and to MXU-friendly multiples via the Pallas kernel's BlockSpecs).
+(and to MXU-friendly multiples of 128 for the Pallas kernel, see
+``repro.kernels.ops.pad_gemm_program``).
 
 Traversal strategy — iterative gather-stepping over padded node arrays
 (better for deep/narrow trees where the GEMM's O(F·I + I·L) work explodes).
@@ -106,12 +107,18 @@ def build_gemm_program(
 
 
 def gemm_predict(prog: GemmTreeProgram, X: jnp.ndarray) -> jnp.ndarray:
-    """Pure-jnp GEMM-strategy inference (also the Pallas kernel's oracle)."""
-    S = jnp.einsum("nf,tfi->nti", X.astype(jnp.float32), prog.A)
+    """Pure-jnp GEMM-strategy inference (also the Pallas kernel's oracle).
+
+    Every contraction runs at ``Precision.HIGHEST``: the TPU's default f32
+    matmul rounds operands to bf16, which would round the features before
+    the ``S <= B`` compare (flipping leaves) and the leaf values before the
+    sum."""
+    hi = jax.lax.Precision.HIGHEST
+    S = jnp.einsum("nf,tfi->nti", X.astype(jnp.float32), prog.A, precision=hi)
     D = (S <= prog.B[None]).astype(jnp.float32)
-    P = jnp.einsum("nti,til->ntl", D, prog.C)
+    P = jnp.einsum("nti,til->ntl", D, prog.C, precision=hi)
     match = (P == prog.Dcount[None]).astype(jnp.float32)
-    raw = jnp.einsum("ntl,tl->n", match, prog.V) + prog.base
+    raw = jnp.einsum("ntl,tl->n", match, prog.V, precision=hi) + prog.base
     return raw
 
 

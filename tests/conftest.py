@@ -2,7 +2,6 @@
 multi-device behaviour is tested via subprocesses (test_distributed.py)."""
 from __future__ import annotations
 
-import numpy as np
 import pytest
 
 from repro.data.datasets import (
@@ -69,15 +68,3 @@ def hospital_gb(hospital):
 @pytest.fixture(scope="session")
 def hospital_lr(hospital):
     return train_pipeline(hospital, "lr")
-
-
-def predictions_match(a: np.ndarray, b: np.ndarray, max_frac: float = 0.005):
-    """Rounding-tolerant prediction equality: the paper itself reports
-    MLtoSQL/MLtoDNN flip 0.006–0.3% of predictions (f32 vs f64 thresholds)."""
-    a = np.asarray(a).reshape(-1)
-    b = np.asarray(b).reshape(-1)
-    assert a.shape == b.shape
-    frac = float((a != b).mean()) if a.dtype.kind in "iub" else float(
-        (np.abs(a - b) > 1e-4).mean()
-    )
-    assert frac <= max_frac, f"{frac:.4%} of predictions differ"

@@ -74,7 +74,6 @@ def test_hierarchical_psum_matches_flat():
     out = _run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed import hierarchical_psum
 
         mesh = jax.make_mesh((2, 4), ('pod', 'data'))
@@ -86,9 +85,9 @@ def test_hierarchical_psum_matches_flat():
         def hier(v):
             return hierarchical_psum(v, intra_axis='data', inter_axis='pod')
 
-        fa = shard_map(flat, mesh=mesh, in_specs=P(('pod','data'), None),
+        fa = jax.shard_map(flat, mesh=mesh, in_specs=P(('pod','data'), None),
                        out_specs=P(('pod','data'), None))(x)
-        fb = shard_map(hier, mesh=mesh, in_specs=P(('pod','data'), None),
+        fb = jax.shard_map(hier, mesh=mesh, in_specs=P(('pod','data'), None),
                        out_specs=P(('pod','data'), None))(x)
         print('MATCH=', bool(jnp.allclose(fa, fb)))
     """)
@@ -103,7 +102,7 @@ def test_embed_lookup_vocab_sharded_matches_take():
         V, D, B, S = 64, 16, 4, 8
         embed = jax.random.normal(jax.random.PRNGKey(0), (V, D), jnp.float32)
         toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, V)
-        with jax.set_mesh(mesh) if hasattr(jax, 'set_mesh') else mesh:
+        with jax.set_mesh(mesh):
             got = embed_lookup(embed, toks, mesh)
         want = jnp.take(embed, toks, axis=0)
         print('MATCH=', bool(jnp.allclose(got, want, atol=1e-6)))
@@ -120,7 +119,6 @@ def test_compressed_allreduce_inside_shard_map():
     out = _run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed import ef_init, compressed_gradient_update
 
         mesh = jax.make_mesh((4,), ('pod',))
@@ -131,7 +129,7 @@ def test_compressed_allreduce_inside_shard_map():
             out, _ = compressed_gradient_update({'g': gl}, state, axis_name='pod')
             return out['g']
 
-        got = shard_map(body, mesh=mesh, in_specs=P('pod', None),
+        got = jax.shard_map(body, mesh=mesh, in_specs=P('pod', None),
                         out_specs=P('pod', None))(g)
         want = jnp.mean(g, axis=0, keepdims=True)  # psum/4 of per-pod grads
         err = float(jnp.abs(got - want).max())

@@ -181,3 +181,31 @@ def test_tree_gemm_padding_is_inert(hospital):
             jnp.asarray(D), jnp.asarray(V), base=prog.base, interpret=True,
         )
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "N,F,I,L",
+    [(100, 128, 128, 128), (4096, 128, 128, 128), (4096, 6528, 128, 128),
+     (4096, 128, 256, 256)],
+)
+def test_tree_gemm_block_n_fits_vmem_and_the_batch(N, F, I, L):
+    from repro.kernels import VMEM_BUDGET_BYTES
+    from repro.kernels.tree_gemm import tree_gemm_block_n, tree_gemm_vmem_bytes
+
+    bn = tree_gemm_block_n(N, F, I, L)
+    assert bn & (bn - 1) == 0 and 8 <= bn <= 512
+    assert bn <= max(8, 1 << (N - 1).bit_length())  # no padding past the bucket
+    assert tree_gemm_vmem_bytes(bn, F, I, L) <= VMEM_BUDGET_BYTES
+
+
+def test_tree_gemm_runtime_follows_use_pallas(monkeypatch):
+    """``use_pallas=None`` means the tree_gemm kernel on TPU (as for the
+    other kernels) and the XLA einsum elsewhere; EXPLAIN says which."""
+    import repro.tensor.compile as tc
+
+    assert tc.tree_runtime("gemm", None) == "gemm (XLA einsum)"
+    assert tc.tree_runtime("gemm", True) == "gemm (tree_gemm kernel)"
+    assert tc.tree_runtime("traversal", True) == "traversal (XLA gathers)"
+    monkeypatch.setattr(tc, "_on_tpu", lambda: True)
+    assert tc.tree_runtime("gemm", None) == "gemm (tree_gemm kernel)"
+    assert tc.tree_runtime("gemm", False) == "gemm (XLA einsum)"

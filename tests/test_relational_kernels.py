@@ -314,3 +314,120 @@ def test_dimsort_cache_is_content_keyed():
     assert np.array_equal(
         np.asarray(dup["order"]), np.asarray(jnp.argsort(jnp.asarray([5, 1, 5])))
     )
+
+
+# ---------------------------------------------------------------------------
+# qualification: a Join the kernel cannot hold keeps the jnp gather, and
+# EXPLAIN names the choice and its reason
+# ---------------------------------------------------------------------------
+
+
+def _join_explain(dim_rows: int, payload_dtype) -> str:
+    import repro as raven
+    from repro.ml import LogisticRegression, fit_pipeline
+
+    rng = np.random.default_rng(5)
+    dim = {"k": np.arange(dim_rows, dtype=np.int64),
+           "v1": _dyadic(rng, dim_rows).astype(payload_dtype)}
+    fact = {"fk": rng.integers(0, dim_rows, 256).astype(np.int64),
+            "x": _dyadic(rng, 256)}
+    pipe = fit_pipeline(
+        {"x": fact["x"], "v1": dim["v1"][fact["fk"]].astype(np.float32)},
+        (fact["x"] > 0).astype(np.int64), ["x", "v1"], [],
+        LogisticRegression(n_iter=5),
+    )
+    db = raven.connect({"f": fact, "d": dim})
+    db.models.publish("m", pipe)
+    return db.sql(
+        "SELECT COUNT(*) FROM PREDICT(model='m', data=f JOIN d ON fk = k) "
+        "AS p WHERE x > 0"
+    ).prepare(transform="sql").explain()
+
+
+@pytest.mark.parametrize(
+    "dim_rows,dtype,want",
+    [
+        (1024, np.float32, "tensor/kernel: gather_join"),
+        (1024, np.int32, "tensor/jnp: argsort+searchsorted gather "
+                         "(payload columns not all f32)"),
+        (40_000, np.float32, "payload exceeds the kernel's VMEM budget"),
+    ],
+)
+def test_explain_names_the_join_runtime_and_why(dim_rows, dtype, want):
+    explain = _join_explain(dim_rows, dtype)
+    assert "Join[d] on fk=k" in explain
+    assert want in explain
+    assert ("tensor/kernel: segment_agg, filter folded in as mask (groups of "
+            "more than 8192 requests: jnp masked segment ops") in explain
+
+
+def test_gather_join_block_n_respects_the_vmem_budget():
+    from repro.kernels import VMEM_BUDGET_BYTES
+    from repro.kernels.relational import (
+        gather_join_block_n,
+        gather_join_vmem_bytes,
+    )
+
+    assert gather_join_block_n(1024, 2) == 256
+    bn = gather_join_block_n(16_384, 2)
+    assert bn is not None and 8 <= bn < 256
+    assert gather_join_vmem_bytes(bn, 16_384, 2) <= VMEM_BUDGET_BYTES
+    assert gather_join_vmem_bytes(2 * bn, 16_384, 2) > VMEM_BUDGET_BYTES
+    assert gather_join_block_n(100_000, 128) is None
+    with pytest.raises(ValueError, match="cannot hold"):
+        ops.gather_join_op(
+            jnp.zeros((8,), jnp.int32), jnp.arange(100_000, dtype=jnp.int32),
+            jnp.zeros((100_000, 128), jnp.float32), interpret=True,
+        )
+
+
+def test_segment_agg_block_n_respects_the_vmem_budget():
+    from repro.kernels import VMEM_BUDGET_BYTES
+    from repro.kernels.relational import (
+        segment_agg_block_n,
+        segment_agg_max_segments,
+        segment_agg_vmem_bytes,
+    )
+
+    assert segment_agg_block_n(64, 5) == 256
+    bn = segment_agg_block_n(8192, 5)
+    assert bn is not None and 8 <= bn < 256
+    assert segment_agg_vmem_bytes(bn, 8192, 5) <= VMEM_BUDGET_BYTES
+    assert segment_agg_vmem_bytes(2 * bn, 8192, 5) > VMEM_BUDGET_BYTES
+    assert segment_agg_block_n(16_384, 5) is None
+    assert segment_agg_max_segments(5) == 8192
+    with pytest.raises(ValueError, match="cannot hold"):
+        ops.segment_agg_op(
+            jnp.zeros((8, 5), jnp.float32), jnp.ones((8,), jnp.float32),
+            jnp.zeros((8,), jnp.int32), num_segments=16_384, interpret=True,
+        )
+
+
+@pytest.mark.kernel_parity
+def test_aggregate_beyond_the_kernel_keeps_the_jnp_segment_ops(monkeypatch):
+    """A coalesced group with more slots than ``segment_agg`` holds runs the
+    jnp segment ops — bitwise the same answers — and EXPLAIN states the
+    bound."""
+    import repro.kernels.relational as rel
+    import repro.tensor.compile as tc
+
+    emitted = []
+    real_emit = tc.emit_aggregate_kernel
+    monkeypatch.setattr(
+        tc, "emit_aggregate_kernel",
+        lambda *a: emitted.append(a[-1]) or real_emit(*a),
+    )
+    tables = _star_tables(n=150, seed=11)
+    seg = np.sort(np.random.default_rng(2).integers(0, 6, size=150))
+    seg = (seg.astype(np.int32), 6)
+    kernel = _run_mode(tables, "on", monkeypatch, segments=seg)
+    assert emitted == [8]  # the slot bucket of 6 requests
+    monkeypatch.setattr(rel, "segment_agg_block_n", lambda S, C: None)
+    emitted.clear()
+    fallback = _run_mode(tables, "on", monkeypatch, segments=seg)
+    assert emitted == []
+    for k in kernel:
+        _assert_bitwise(kernel[k], fallback[k], f"kernel-vs-fallback {k}")
+    assert tc.aggregate_kernel_choice([("n", "count", "x")], 8) == (
+        "8 segments exceed the kernel's VMEM budget"
+    )

@@ -344,3 +344,24 @@ def test_forced_donation_split_matches_plain(db, monkeypatch):
     assert set(ref) == set(got)
     for k in ref:
         np.testing.assert_allclose(got[k], ref[k], rtol=1e-6)
+
+
+def test_aggregate_stage_does_not_donate(db, monkeypatch):
+    """An aggregate folds the rows away, so no output can alias a donated
+    row buffer: even with donation forced on, such a stage runs the plain
+    program (XLA would otherwise warn the donation was unusable)."""
+    import warnings
+
+    monkeypatch.setenv("RAVEN_DONATE", "1")
+    srv = PredictionQueryServer()
+    db.sql(
+        "SELECT COUNT(*), AVG(score) FROM PREDICT(model='m', data=patients) "
+        "AS p WHERE score >= :t"
+    ).prepare(transform="sql", params={"t": 0.6}).serve(
+        name="agg_don", server=srv,
+    )
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        out = srv.execute("agg_don", _batch(200, seed=4))
+    assert out["count_rows"].shape == (1,)
+    assert not [w for w in caught if "donated" in str(w.message)]

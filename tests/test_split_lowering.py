@@ -147,8 +147,13 @@ def _check_split_matches_host(k, n, udf_pos, offsets, scales, arr):
 
 
 if HAVE_HYPOTHESIS:
-    finite_f32 = st.floats(
-        min_value=-1e3, max_value=1e3, allow_nan=False, width=32
+    # XLA:CPU flushes subnormal operands and results to zero where numpy
+    # keeps them, so values are 0 or at least 2**-10 in magnitude: every
+    # difference, product and udf result of them is then 0 or normal
+    finite_f32 = st.one_of(
+        st.just(0.0),
+        st.floats(min_value=2.0**-10, max_value=1e3, width=32),
+        st.floats(min_value=-1e3, max_value=-(2.0**-10), width=32),
     )
 
     @settings(max_examples=25, deadline=None)
