@@ -1,9 +1,8 @@
-"""Benchmark driver: one module per paper table/figure + the roofline report.
+"""Benchmark runner: one module per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig6,fig10,...]
 
-Prints CSV blocks per figure (the same rows each module prints standalone)
-and finishes with the §Roofline table from the dry-run records.
+Prints CSV blocks per figure (the same rows each module prints standalone).
 """
 from __future__ import annotations
 
@@ -62,14 +61,6 @@ def main() -> None:
             failures += 1
         print(f"# {name} done in {time.time()-t0:.1f}s")
 
-    print("\n# === roofline (single-pod, from dry-run records) ===")
-    try:
-        from benchmarks.roofline import report
-
-        print(report("sp"))
-    except Exception:
-        traceback.print_exc()
-        failures += 1
     print(f"\n# all benchmarks done in {time.time()-t_all:.1f}s; "
           f"{failures} failures")
     sys.exit(1 if failures else 0)

@@ -426,7 +426,6 @@ def run_mixed(db, sql, quick: bool = False) -> dict:
             "small_p50_ms": float(np.percentile(s_lat, 50)),
             "small_p99_ms": float(np.percentile(s_lat, 99)),
             "retraces_after_warmup": retraces,
-            "overlap_s": snap["pipeline"]["overlap_s"],
             "overlapped_groups": snap["pipeline"]["overlapped_groups"],
         }
 
@@ -448,8 +447,7 @@ def run_mixed(db, sql, quick: bool = False) -> dict:
     speedup = serial["wall_s"] / piped["wall_s"]
     print(f"serve_query_mixed,speedup,pipelined vs serial = {speedup:.2f}x "
           f"at parallel_efficiency={eff:.2f} "
-          f"(overlap {piped['overlap_s']:.2f}s across "
-          f"{piped['overlapped_groups']} groups; small-query p99 "
+          f"({piped['overlapped_groups']} groups overlapped; small-query p99 "
           f"{serial['small_p99_ms']:.1f} -> {piped['small_p99_ms']:.1f} ms "
           f"at a {small_target_ms:.0f} ms target)")
     if eff < 1.4:
@@ -469,7 +467,6 @@ def run_mixed(db, sql, quick: bool = False) -> dict:
         "mixed_small_p99_pipelined_ms": piped["small_p99_ms"],
         "mixed_heavy_p99_pipelined_ms": piped["heavy_p99_ms"],
         "mixed_pipelined_retraces_after_warmup": piped["retraces_after_warmup"],
-        "mixed_overlap_s": piped["overlap_s"],
         "mixed_overlapped_groups": piped["overlapped_groups"],
     }
 

@@ -23,8 +23,9 @@ as a pipeline over request groups:
     latency-sensitive pure queries out of the boundary pool's queue, so a
     large host-bound group can never sit in front of them.
 
-The executor also owns the pipelining gauges (groups in flight, overlap
-wall time, host-pool busy time) surfaced through ``db.cache_stats()``.
+The executor also owns the pipelining gauges (groups in flight, groups
+that began while another ran) surfaced through ``db.cache_stats()``; where
+the time goes is read from the ``raven.*`` spans (:mod:`repro.obs`).
 """
 from __future__ import annotations
 
@@ -57,9 +58,6 @@ class PipelineExecutor:
         self.max_groups_in_flight = 0
         self.groups_started = 0
         self.overlapped_groups = 0  # groups that began while another ran
-        self.overlap_s = 0.0        # wall time with >= 2 groups in flight
-        self.host_busy_s = 0.0      # wall time spent inside host boundaries
-        self._t_mark: float = 0.0
 
     @property
     def pool(self) -> ThreadPoolExecutor:
@@ -93,23 +91,12 @@ class PipelineExecutor:
                 "max_groups_in_flight": self.max_groups_in_flight,
                 "groups_started": self.groups_started,
                 "overlapped_groups": self.overlapped_groups,
-                "overlap_s": self.overlap_s,
-                "host_busy_s": self.host_busy_s,
             }
 
-    # -- in-flight / overlap accounting --------------------------------------
-
-    def _accrue(self, now: float) -> None:
-        # caller holds _lock; overlap accumulates only while >= 2 groups
-        # were simultaneously in flight since the last transition
-        if self.groups_in_flight >= 2:
-            self.overlap_s += now - self._t_mark
-        self._t_mark = now
+    # -- in-flight accounting -------------------------------------------------
 
     def _enter_group(self) -> None:
         with self._lock:
-            now = time.perf_counter()
-            self._accrue(now)
             if self.groups_in_flight >= 1:
                 self.overlapped_groups += 1
             self.groups_in_flight += 1
@@ -120,7 +107,6 @@ class PipelineExecutor:
 
     def _exit_group(self) -> None:
         with self._lock:
-            self._accrue(time.perf_counter())
             self.groups_in_flight -= 1
 
     # -- the pipelined walk ---------------------------------------------------
@@ -133,6 +119,7 @@ class PipelineExecutor:
         bucketer: Optional[Callable[[int], int]] = None,
         on_mid_bucket: Optional[Callable[[int, int], None]] = None,
         donate: frozenset = frozenset(),
+        group: int = 0,
     ) -> "Future[RunResult]":
         """Execute ``graph`` with host/device overlap; returns a future.
 
@@ -140,13 +127,13 @@ class PipelineExecutor:
         same stage callables run over the same env structure — only the
         synchronization points move: pure stages are dispatched without
         waiting, and each host boundary (plus everything after it) runs on
-        the boundary pool.
+        the boundary pool. ``group`` is the dispatch id the spans carry.
         """
         fut: Future = Future()
         self._enter_group()
         try:
             self._advance(graph, 0, None, env, bucketer, on_mid_bucket,
-                          donate, [], fut)
+                          donate, group, fut)
         except BaseException as e:  # noqa: BLE001 — delivered via the future
             self._finish(fut, error=e)
         return fut
@@ -160,7 +147,7 @@ class PipelineExecutor:
         bucketer,
         on_mid_bucket,
         donate: frozenset,
-        timings: list[float],
+        group: int,
         fut: Future,
     ) -> None:
         """Run stages from ``start`` on the current thread until the next
@@ -169,7 +156,7 @@ class PipelineExecutor:
             stage = graph.stages[i]
             t0 = time.perf_counter()
             if stage.kind == "pure":
-                state = call_pure(stage, env, donate)
+                state = call_pure(stage, env, donate, group)
                 dt = time.perf_counter() - t0
                 if stage.index == 0:
                     env = strip_consumed(env, donate)
@@ -180,7 +167,6 @@ class PipelineExecutor:
                     # the serial runner's blocking-wall measure
                     stage.async_calls += 1
                     stage.dispatch_s += dt
-                timings.append(dt)
                 continue
 
             # host boundary: everything from here on runs on the pool, and
@@ -193,6 +179,7 @@ class PipelineExecutor:
                     new_state, new_env = host_step(
                         _stage, _state, _env,
                         bucketer=bucketer, on_mid_bucket=on_mid_bucket,
+                        group=group,
                     )
                 except BaseException as e:  # noqa: BLE001
                     self._finish(fut, error=e)
@@ -203,12 +190,10 @@ class PipelineExecutor:
                     _stage.total_s += dt1
                     _stage.async_calls += 1
                     _stage.dispatch_s += dt1
-                    self.host_busy_s += dt1
-                timings.append(dt1)
                 try:
                     self._advance(graph, _i + 1, new_state, new_env,
                                   bucketer, on_mid_bucket, donate,
-                                  timings, fut)
+                                  group, fut)
                 except BaseException as e:  # noqa: BLE001
                     self._finish(fut, error=e)
 
@@ -217,7 +202,7 @@ class PipelineExecutor:
 
         cols, valid, seg = state
         self._finish(fut, result=RunResult(
-            table=Table(columns=cols, valid=valid), seg=seg, timings=timings,
+            table=Table(columns=cols, valid=valid), seg=seg
         ))
 
     def _finish(self, fut: Future, *, result=None, error=None) -> None:

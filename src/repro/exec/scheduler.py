@@ -40,6 +40,7 @@ so the scheduler works with no pump thread at all.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -54,6 +55,7 @@ from repro.errors import (
     TransientError,
 )
 from repro.exec.faults import RetryPolicy, maybe_inject
+from repro.obs import span
 
 
 @dataclass
@@ -121,6 +123,9 @@ class Scheduler:
         # had popped *before* it was called (bounded under sustained load)
         self._pump_started = 0
         self._pump_settled = 0
+        # dispatch ids: every popped group (a retry too) takes the next one
+        # and stamps it on its requests as ``group``
+        self._group_ids = itertools.count(1)
         # counters (reads are advisory; mutations under _cv)
         self.flushes = 0  # pump-initiated group dispatches
         self.backpressure_waits = 0
@@ -128,6 +133,10 @@ class Scheduler:
         self.max_queue_depth = 0
         self.retries = 0            # groups requeued after a transient failure
         self.retries_exhausted = 0  # groups failed terminally after retries
+        # fresh requests' summed wait from submit to pop (us), and how many
+        # requests that sum covers
+        self.queue_wait_us = 0
+        self.queue_waits = 0
         self.last_error: Optional[BaseException] = None
 
     # -- queue management -----------------------------------------------------
@@ -180,6 +189,8 @@ class Scheduler:
                 "max_queue_depth": self.max_queue_depth,
                 "retries": self.retries,
                 "retries_exhausted": self.retries_exhausted,
+                "queue_wait_us": self.queue_wait_us,
+                "queue_waits": self.queue_waits,
                 "redo_depth": sum(
                     len(q.redo) for q in self._queues.values()
                 ),
@@ -316,13 +327,16 @@ class Scheduler:
         coalesced group) ahead of fresh requests, else the head of the
         fresh queue up to its coalesce-width cap. Returns
         ``(group, attempt)``; fresh groups are attempt 0. ``due_only=False``
-        (drain) ignores backoff expiry — a flush means "serve now"."""
+        (drain) ignores backoff expiry — a flush means "serve now". Stamps
+        the group's dispatch id on its requests, and adds the fresh
+        requests' wait since submit to ``queue_wait_us``."""
         now = time.perf_counter()
         for i, (group, attempt, nb) in enumerate(q.redo):
             if due_only and nb > now:
                 continue
             del q.redo[i]
             q.last_pop = now
+            self._stamp(group)
             self._cv.notify_all()
             return group, attempt
         cap = (
@@ -339,6 +353,9 @@ class Scheduler:
             group.append(req)
             rows += n
         q.last_pop = now
+        self._stamp(group)
+        self.queue_wait_us += int(1e6 * sum(now - r.t_submit for r in group))
+        self.queue_waits += len(group)
         self._cv.notify_all()  # wake backpressured submitters
         if asserts_enabled():
             runtime_assert(len(group) >= 1, "popped an empty group")
@@ -348,6 +365,11 @@ class Scheduler:
                 f"popped group for '{q.name}' contains duplicate requests",
             )
         return group, 0
+
+    def _stamp(self, group: list) -> None:
+        gid = next(self._group_ids)
+        for r in group:
+            r.group = gid
 
     def _loop(self) -> None:
         while True:
@@ -448,15 +470,19 @@ class Scheduler:
         return terminal
 
     def _dispatch_safe(self, name: str, group: list) -> "Future":
-        try:
-            # "worker" fault site: the scheduler worker dies mid-dispatch —
-            # the popped group must flow into the retry path, never be lost
-            maybe_inject("worker", token=name)
-            return self._dispatch(name, group)
-        except BaseException as e:  # noqa: BLE001 — contain; requests carry it
-            f: Future = Future()
-            f.set_exception(e)
-            return f
+        rows = sum(getattr(r, "n_rows", 0) for r in group)
+        with span("raven.group", group=group[0].group, requests=len(group),
+                  rows=rows):
+            try:
+                # "worker" fault site: the scheduler worker dies mid-dispatch
+                # — the popped group must flow into the retry path, never be
+                # lost
+                maybe_inject("worker", token=name)
+                return self._dispatch(name, group)
+            except BaseException as e:  # noqa: BLE001 — requests carry it
+                f: Future = Future()
+                f.set_exception(e)
+                return f
 
     # -- the synchronous path -------------------------------------------------
 

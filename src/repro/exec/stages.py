@@ -30,13 +30,14 @@ from __future__ import annotations
 
 import os
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import span
 from repro.relational.expr import eval_expr, params_of
 from repro.relational.table import Table
 
@@ -641,21 +642,24 @@ def run_udf(udf, cols: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
 
 @dataclass
 class RunResult:
-    """One graph execution: the result table, the per-row segment ids it
-    carried (None outside coalesced serving), and per-stage wall times."""
+    """One graph execution: the result table and the per-row segment ids it
+    carried (None outside coalesced serving)."""
 
     table: Table
     seg: Optional[jnp.ndarray]
-    timings: list[float] = field(default_factory=list)
 
 
 def call_pure(stage: Stage, env: dict[str, Any],
-              donate: frozenset = frozenset()) -> State:
+              donate: frozenset = frozenset(), group: int = 0) -> State:
     """Invoke one pure stage — the jitted runner when the engine installed
-    one (it understands the donation set), else the raw composed fn."""
-    if stage.runner is not None:
-        return stage.runner(env, donate=donate)
-    return stage.fn(env)
+    one (it understands the donation set), else the raw composed fn —
+    inside a ``raven.stage`` span; ``group`` is the serving dispatch id
+    (0 outside serving)."""
+    with span("raven.stage", group=group, stage=stage.index,
+              fp=stage.fingerprint[:12]):
+        if stage.runner is not None:
+            return stage.runner(env, donate=donate)
+        return stage.fn(env)
 
 
 def strip_consumed(env: dict[str, Any], donate: frozenset) -> dict[str, Any]:
@@ -681,10 +685,12 @@ def host_step(
     *,
     bucketer: Optional[Callable[[int], int]] = None,
     on_mid_bucket: Optional[Callable[[int, int], None]] = None,
+    group: int = 0,
 ) -> tuple[State, dict[str, Any]]:
-    """Run one MLUdf host boundary: synchronize the upstream device state,
-    compact to valid rows, run the interpreted pipeline, re-pad the output
-    to a shape bucket, and re-wrap it as the ``__mid__`` pseudo-table.
+    """Run one MLUdf host boundary inside a ``raven.host_boundary`` span:
+    synchronize the upstream device state, compact to valid rows, run the
+    interpreted pipeline, re-pad the output to a shape bucket, and re-wrap
+    it as the ``__mid__`` pseudo-table.
 
     This is the graph's only synchronization point — ``np.asarray`` blocks
     on the device work the upstream pure stages dispatched — which is what
@@ -694,35 +700,37 @@ def host_step(
     """
     from repro.exec.faults import maybe_inject
 
-    # "udf" fault site: the interpreted ML runtime raises at the host
-    # boundary (the Spark→Python-UDF failure mode), before any device sync
-    maybe_inject("udf", token=stage.fingerprint)
-    cols, valid, seg = state
-    np_cols = {k: np.asarray(v) for k, v in cols.items()}
-    mask = np.asarray(valid)
-    np_cols = {k: v[mask] for k, v in np_cols.items()}  # compact
-    np_seg = np.asarray(seg)[mask] if seg is not None else None
-    out = run_udf(stage.udf, np_cols)
-    n = len(next(iter(out.values()))) if out else 0
-    b = bucketer(n) if bucketer is not None else n
-    if b > n:
-        out = {
-            k: np.concatenate([v, np.zeros((b - n,) + v.shape[1:], dtype=v.dtype)])
-            for k, v in out.items()
-        }
+    with span("raven.host_boundary", group=group, stage=stage.index,
+              fp=stage.fingerprint[:12]):
+        # "udf" fault site: the interpreted ML runtime raises at the host
+        # boundary (the Spark→Python-UDF failure mode), before any device sync
+        maybe_inject("udf", token=stage.fingerprint)
+        cols, valid, seg = state
+        np_cols = {k: np.asarray(v) for k, v in cols.items()}
+        mask = np.asarray(valid)
+        np_cols = {k: v[mask] for k, v in np_cols.items()}  # compact
+        np_seg = np.asarray(seg)[mask] if seg is not None else None
+        out = run_udf(stage.udf, np_cols)
+        n = len(next(iter(out.values()))) if out else 0
+        b = bucketer(n) if bucketer is not None else n
+        if b > n:
+            out = {
+                k: np.concatenate([v, np.zeros((b - n,) + v.shape[1:], dtype=v.dtype)])
+                for k, v in out.items()
+            }
+            if np_seg is not None:
+                np_seg = np.concatenate(
+                    [np_seg, np.zeros(b - n, dtype=np_seg.dtype)]
+                )
+        if on_mid_bucket is not None:
+            on_mid_bucket(stage.index, b)
+        mid = {k: jnp.asarray(v) for k, v in out.items()}
+        mid[MID_VALID] = jnp.asarray(np.arange(b) < n)
         if np_seg is not None:
-            np_seg = np.concatenate(
-                [np_seg, np.zeros(b - n, dtype=np_seg.dtype)]
-            )
-    if on_mid_bucket is not None:
-        on_mid_bucket(stage.index, b)
-    mid = {k: jnp.asarray(v) for k, v in out.items()}
-    mid[MID_VALID] = jnp.asarray(np.arange(b) < n)
-    if np_seg is not None:
-        mid[MID_SEG] = jnp.asarray(np_seg, dtype=jnp.int32)
-    env = dict(env)
-    env[MID_TABLE] = mid
-    return _from_mid(env), env
+            mid[MID_SEG] = jnp.asarray(np_seg, dtype=jnp.int32)
+        env = dict(env)
+        env[MID_TABLE] = mid
+        return _from_mid(env), env
 
 
 def run_graph(
@@ -732,6 +740,7 @@ def run_graph(
     bucketer: Optional[Callable[[int], int]] = None,
     on_mid_bucket: Optional[Callable[[int, int], None]] = None,
     donate: frozenset = frozenset(),
+    group: int = 0,
 ) -> RunResult:
     """Execute a stage graph over an environment, one stage at a time.
 
@@ -743,6 +752,7 @@ def run_graph(
     one-shot ``execute_plan`` path). ``donate`` names env tables whose
     buffers are single-use (the serving layer's freshly padded fact spine)
     and may be aliased into stage outputs on accelerator backends.
+    ``group`` is the serving dispatch id the stage spans carry.
 
     This serial runner blocks at every stage; the pipelined executor in
     :mod:`repro.exec.pipeline` runs the same stages — same jitted programs,
@@ -750,23 +760,19 @@ def run_graph(
     groups.
     """
     state: Optional[State] = None
-    timings: list[float] = []
     for stage in graph.stages:
         t0 = time.perf_counter()
         if stage.kind == "pure":
-            state = call_pure(stage, env, donate)
+            state = call_pure(stage, env, donate, group)
             jax.block_until_ready(state[:2])
             if stage.index == 0:
                 env = strip_consumed(env, donate)
         else:
             state, env = host_step(
                 stage, state, env,
-                bucketer=bucketer, on_mid_bucket=on_mid_bucket,
+                bucketer=bucketer, on_mid_bucket=on_mid_bucket, group=group,
             )
-        dt = time.perf_counter() - t0
         stage.calls += 1
-        stage.total_s += dt
-        timings.append(dt)
+        stage.total_s += time.perf_counter() - t0
     cols, valid, seg = state
-    return RunResult(table=Table(columns=cols, valid=valid), seg=seg,
-                     timings=timings)
+    return RunResult(table=Table(columns=cols, valid=valid), seg=seg)

@@ -373,6 +373,7 @@ class CompiledPlan:
         bucketer: Optional[Callable[[int], int]] = None,
         on_mid_bucket: Optional[Callable[[int, int], None]] = None,
         donate: frozenset = frozenset(),
+        group: int = 0,
     ) -> RunResult:
         """Execute the stage graph; the full-fidelity serving entry point.
 
@@ -381,12 +382,12 @@ class CompiledPlan:
         boundary outputs to shape buckets so post-UDF stages stay warm;
         ``donate`` names fact tables whose (single-use, freshly padded)
         buffers the entry stage may alias into its outputs on accelerator
-        backends.
+        backends; ``group`` is the serving dispatch id the stage spans carry.
         """
         env = self._env(database, row_valid, params, segments)
         return run_graph(
             self.graph, env, bucketer=bucketer, on_mid_bucket=on_mid_bucket,
-            donate=frozenset(donate),
+            donate=frozenset(donate), group=group,
         )
 
     def run_async(
@@ -400,6 +401,7 @@ class CompiledPlan:
         bucketer: Optional[Callable[[int], int]] = None,
         on_mid_bucket: Optional[Callable[[int, int], None]] = None,
         donate: frozenset = frozenset(),
+        group: int = 0,
     ):
         """Pipelined execution: returns a ``Future[RunResult]``.
 
@@ -413,7 +415,7 @@ class CompiledPlan:
         env = self._env(database, row_valid, params, segments)
         return executor.run_graph_async(
             self.graph, env, bucketer=bucketer, on_mid_bucket=on_mid_bucket,
-            donate=frozenset(donate),
+            donate=frozenset(donate), group=group,
         )
 
     def __call__(

@@ -59,6 +59,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -86,6 +87,7 @@ from repro.exec.faults import RetryPolicy, get_fault_plan, maybe_inject
 from repro.exec.pipeline import PipelineExecutor
 from repro.exec.scheduler import Scheduler
 from repro.exec.stages import seg_bucket
+from repro.obs import span
 from repro.relational.engine import (
     Aggregate,
     CompiledPlan,
@@ -137,6 +139,7 @@ class QueryRequest:
     error: Optional[BaseException] = None  # execution failure, re-raised by wait()
     t_submit: float = 0.0
     t_done: float = 0.0
+    group: int = 0  # dispatch id of the group that last took it
     _event: threading.Event = field(
         default_factory=threading.Event, repr=False, compare=False
     )
@@ -923,23 +926,26 @@ class PredictionQueryServer:
         # the caller provided (not just the live version's scan set): shadow
         # and split versions of the same route may read columns the live plan
         # pruned away, and the group must carry enough for all of them.
-        cols = {
-            c: np.asarray(v).astype(reg.fact_dtypes[c], copy=False)
-            for c, v in columns.items()
-            if c in reg.fact_dtypes
-        }
-        lengths = {len(v) for v in cols.values()}
-        if len(lengths) > 1:
-            raise ValueError(
-                f"batch for '{name}' has ragged columns: "
-                f"{ {c: len(v) for c, v in cols.items()} }"
+        rid = next(self._rid)
+        rows = len(next(iter(columns.values()))) if columns else 0
+        with span("raven.submit", rid=rid, rows=rows):
+            cols = {
+                c: np.asarray(v).astype(reg.fact_dtypes[c], copy=False)
+                for c, v in columns.items()
+                if c in reg.fact_dtypes
+            }
+            lengths = {len(v) for v in cols.values()}
+            if len(lengths) > 1:
+                raise ValueError(
+                    f"batch for '{name}' has ragged columns: "
+                    f"{ {c: len(v) for c, v in cols.items()} }"
+                )
+            n = lengths.pop() if lengths else 0
+            req = QueryRequest(
+                rid=rid, query=name, columns=cols, n_rows=n,
+                t_submit=time.perf_counter(),
             )
-        n = lengths.pop() if lengths else 0
-        req = QueryRequest(
-            rid=next(self._rid), query=name, columns=cols, n_rows=n,
-            t_submit=time.perf_counter(),
-        )
-        self.scheduler.enqueue(name, req, n, block=block, timeout=timeout)
+            self.scheduler.enqueue(name, req, n, block=block, timeout=timeout)
         with self._lock:
             self.stats.rows_in += n
         return req
@@ -1041,7 +1047,8 @@ class PredictionQueryServer:
             with self._lock:
                 self.stats.pipelined_groups += 1
             cat, n, segments = self._group_batch(reg, group)
-            gfut = self._execute_padded_async(reg, cat, n, segments=segments)
+            gfut = self._execute_padded_async(reg, cat, n, segments=segments,
+                                              group=group[0].group)
 
             def _complete(f2, _reg=reg, _group=group, _n=n, _done=done):
                 try:
@@ -1191,7 +1198,8 @@ class PredictionQueryServer:
                     [r.n_rows for r in group],
                 )
                 segments = (seg_ids, len(group))
-            res = self._execute_padded(shadow_reg, cat, n, segments=segments)
+            res = self._execute_padded(shadow_reg, cat, n, segments=segments,
+                                       group=group[0].group)
             shadow_out = self._split_results(shadow_reg, group, res, n)
             primary_done.result(timeout=60.0)
             diff_rows, max_diff, rows = self._diff_shadow(group, shadow_out)
@@ -1284,18 +1292,21 @@ class PredictionQueryServer:
         fact_np: dict[str, np.ndarray],
         n: int,
         segments: Optional[tuple[np.ndarray, int]] = None,
+        group: int = 0,
     ) -> dict[str, Any]:
-        """Pad ``n`` fact rows to their bucket; returns the kwargs shared by
+        """Pad ``n`` fact rows to their bucket and copy them to the device
+        (the ``raven.h2d`` span); returns the kwargs shared by
         ``CompiledPlan.run`` and ``run_async`` (plus bucket accounting)."""
         bucket = row_bucket(n, self.min_bucket)
-        fact: dict[str, jnp.ndarray] = {}
-        for c in reg.scan_columns:
-            col = fact_np[c]
-            if len(col) < bucket:
-                pad = np.zeros(bucket - len(col), dtype=col.dtype)
-                col = np.concatenate([col, pad])
-            fact[c] = jnp.asarray(col)
-        row_valid = np.arange(bucket) < n
+        with span("raven.h2d", group=group):
+            fact: dict[str, jnp.ndarray] = {}
+            for c in reg.scan_columns:
+                col = fact_np[c]
+                if len(col) < bucket:
+                    pad = np.zeros(bucket - len(col), dtype=col.dtype)
+                    col = np.concatenate([col, pad])
+                fact[c] = jnp.asarray(col)
+            row_valid = jnp.asarray(np.arange(bucket) < n)
         if segments is not None:
             ids, k = segments
             if len(ids) < bucket:
@@ -1340,7 +1351,7 @@ class PredictionQueryServer:
         db[reg.fact_table] = fact
         return {
             "database": db,
-            "row_valid": jnp.asarray(row_valid),
+            "row_valid": row_valid,
             "params": reg.params if reg.param_names else None,
             "segments": segments,
             "bucketer": (
@@ -1352,6 +1363,7 @@ class PredictionQueryServer:
             # donate to XLA on backends that support aliasing (unless the
             # registration opted out via ServeOptions(donate=False))
             "donate": frozenset((reg.fact_table,)) if reg.donate else frozenset(),
+            "group": group,
         }
 
     def _execute_padded(
@@ -1360,9 +1372,12 @@ class PredictionQueryServer:
         fact_np: dict[str, np.ndarray],
         n: int,
         segments: Optional[tuple[np.ndarray, int]] = None,
+        group: int = 0,
     ):
         """Serial padded execution (blocks at every stage)."""
-        return reg.active.run(**self._padded_kwargs(reg, fact_np, n, segments))
+        return reg.active.run(
+            **self._padded_kwargs(reg, fact_np, n, segments, group)
+        )
 
     def _execute_padded_async(
         self,
@@ -1370,11 +1385,12 @@ class PredictionQueryServer:
         fact_np: dict[str, np.ndarray],
         n: int,
         segments: Optional[tuple[np.ndarray, int]] = None,
+        group: int = 0,
     ) -> Future:
         """Pipelined padded execution; returns ``Future[RunResult]``."""
         return reg.active.run_async(
             executor=self.executor,
-            **self._padded_kwargs(reg, fact_np, n, segments),
+            **self._padded_kwargs(reg, fact_np, n, segments, group),
         )
 
     def _finish(self, req: QueryRequest) -> None:
@@ -1433,29 +1449,35 @@ class PredictionQueryServer:
     ) -> list[dict[str, np.ndarray]]:
         """Split one executed group's table into per-request column dicts —
         pure (no request mutation), shared by the primary finish path and
-        the shadow diff path."""
-        if reg.sliceable:
-            cols = {
-                k: np.asarray(v)[:n] for k, v in res.table.columns.items()
-            }
-            valid = np.asarray(res.table.valid)[:n]
-            return self._positional_results(group, cols, valid)
-        if len(group) == 1:
-            # a lone host-boundary/aggregate request: no splitting needed
-            return [res.table.to_numpy(compact=True)]
-        cols = {k: np.asarray(v) for k, v in res.table.columns.items()}
-        valid = np.asarray(res.table.valid)
-        if reg.has_aggregate:
-            # segmented fold: output row i belongs to request i
+        the shadow diff path. Waits for the device buffers first (the
+        ``raven.device_wait`` span), so that the ``raven.d2h`` span holds
+        the copies alone."""
+        gid = group[0].group
+        with span("raven.device_wait", group=gid):
+            jax.block_until_ready((res.table.columns, res.table.valid, res.seg))
+        with span("raven.d2h", group=gid):
+            if reg.sliceable:
+                cols = {
+                    k: np.asarray(v)[:n] for k, v in res.table.columns.items()
+                }
+                valid = np.asarray(res.table.valid)[:n]
+                return self._positional_results(group, cols, valid)
+            if len(group) == 1:
+                # a lone host-boundary/aggregate request: no splitting needed
+                return [res.table.to_numpy(compact=True)]
+            cols = {k: np.asarray(v) for k, v in res.table.columns.items()}
+            valid = np.asarray(res.table.valid)
+            if reg.has_aggregate:
+                # segmented fold: output row i belongs to request i
+                return [
+                    {k: v[i:i + 1] for k, v in cols.items()}
+                    for i in range(len(group))
+                ]
+            seg = np.asarray(res.seg)
             return [
-                {k: v[i:i + 1] for k, v in cols.items()}
+                {k: v[valid & (seg == i)] for k, v in cols.items()}
                 for i in range(len(group))
             ]
-        seg = np.asarray(res.seg)
-        return [
-            {k: v[valid & (seg == i)] for k, v in cols.items()}
-            for i in range(len(group))
-        ]
 
     def _split_group(
         self,
@@ -1479,21 +1501,25 @@ class PredictionQueryServer:
             # row-aligned output lets a spine wider than max_bucket run as
             # max_bucket-sized chunks, keeping the compiled-program count
             # bounded by log2(max_bucket / min_bucket) + 1 per query
+            gid = group[0].group
             out_cols: dict[str, list[np.ndarray]] = {}
             out_valid: list[np.ndarray] = []
             for off in range(0, max(n, 1), self.max_bucket):
-                span = min(self.max_bucket, n - off) if n else 0
-                chunk = {c: v[off:off + span] for c, v in cat.items()}
-                table = self._execute_padded(reg, chunk, span).table
-                valid = np.asarray(table.valid)[:span]
-                out_valid.append(valid)
-                for k, v in table.columns.items():
-                    out_cols.setdefault(k, []).append(np.asarray(v)[:span])
-            cols = {k: np.concatenate(v) for k, v in out_cols.items()}
-            valid = np.concatenate(out_valid)
-            self._positional_split(group, cols, valid)
+                rows = min(self.max_bucket, n - off) if n else 0
+                chunk = {c: v[off:off + rows] for c, v in cat.items()}
+                # the serial runner returns once the device is done
+                table = self._execute_padded(reg, chunk, rows, group=gid).table
+                with span("raven.d2h", group=gid):
+                    out_valid.append(np.asarray(table.valid)[:rows])
+                    for k, v in table.columns.items():
+                        out_cols.setdefault(k, []).append(np.asarray(v)[:rows])
+            with span("raven.d2h", group=gid):
+                cols = {k: np.concatenate(v) for k, v in out_cols.items()}
+                valid = np.concatenate(out_valid)
+                self._positional_split(group, cols, valid)
             return
-        res = self._execute_padded(reg, cat, n, segments=segments)
+        res = self._execute_padded(reg, cat, n, segments=segments,
+                                   group=group[0].group)
         self._split_group(reg, group, res, n)
 
     # -- introspection --------------------------------------------------------

@@ -264,10 +264,11 @@ class Session:
         ``stage_traces`` keyed by stage fingerprint) merged with the session
         server's :class:`ServerStats` under ``"server"`` — including the
         scheduler's queue gauges (``queue_depths``, ``max_queue_depth``,
-        ``backpressure_waits``, ``overloads``) and the pipelined executor's
-        overlap gauges under ``"server"]["pipeline"`` (groups in flight,
-        ``overlap_s`` wall time with ≥2 groups overlapping, host-pool busy
-        time) — and, when the session was opened with ``cache_dir``, the
+        ``backpressure_waits``, ``overloads``, and ``queue_wait_us`` /
+        ``queue_waits``: the summed submit-to-pop wait and the requests it
+        covers) and the pipelined executor's gauges under
+        ``"server"]["pipeline"`` (groups in flight, groups that overlapped
+        another) — and, when the session was opened with ``cache_dir``, the
         artifact store's :class:`~repro.exec.artifact_store.StoreStats`
         under ``"artifact_store"``, so benchmarks and tests can assert
         zero-retrace warm paths without reaching into module globals.
