@@ -16,16 +16,18 @@ and ``rid`` the request id, so one request's spans can be joined):
 ``raven.group`` (group, requests, rows)
     one popped group, from dispatch until the dispatch returns (for a
     fused graph that includes its completion), on the scheduler thread.
-``raven.h2d`` (group)
-    padding and the per-column copies to the device.
+``raven.h2d`` (group, arrays)
+    padding and the one batched copy of the group's input columns,
+    validity and segment ids to the device; ``arrays`` counts what it moved.
 ``raven.stage`` (group, stage, fp)
     one pure stage's call: its dispatch, or its run where the caller blocks.
 ``raven.host_boundary`` (group, stage, fp)
     one MLUdf host boundary.
 ``raven.device_wait`` (group)
     waiting for the result's device buffers, before they are copied.
-``raven.d2h`` (group)
-    copying the result to the host and splitting it per request.
+``raven.d2h`` (group, arrays)
+    the one batched copy of the result to the host and its split per
+    request; ``arrays`` counts the columns, validity and segment ids moved.
 """
 from __future__ import annotations
 

@@ -112,8 +112,8 @@ def canonical_dtype(dt: np.dtype) -> np.dtype:
 
     Registered schemas and submitted batches are normalized to this *at
     submit time*, on the submitter's thread: converting float64 → float32
-    during ``jnp.asarray`` is an element-wise cast, and paying it per group
-    on the scheduler thread serializes the whole server behind it. After
+    during the copy to the device is an element-wise cast, and paying it per
+    group on the scheduler thread serializes the whole server behind it. After
     normalization the serving path's host→device transfers are plain
     memcpys.
     """
@@ -123,6 +123,17 @@ def canonical_dtype(dt: np.dtype) -> np.dtype:
     if dt.kind in "iu" and dt.itemsize > 4:
         return np.dtype(np.int32)
     return dt
+
+
+def _to_host(
+    columns: dict[str, Any], valid: Any, seg: Any = None
+) -> tuple[dict[str, np.ndarray], np.ndarray, Optional[np.ndarray]]:
+    """A group's result columns, validity and (where given) segment ids as
+    numpy arrays, through one ``jax.device_get``: it starts every column's
+    copy to the host before it waits on any, so the copies overlap rather
+    than each waiting out the one before."""
+    out = jax.device_get({"columns": dict(columns), "valid": valid, "seg": seg})
+    return out["columns"], out["valid"], out["seg"]
 
 
 @dataclass
@@ -1295,25 +1306,31 @@ class PredictionQueryServer:
         group: int = 0,
     ) -> dict[str, Any]:
         """Pad ``n`` fact rows to their bucket and copy them to the device
-        (the ``raven.h2d`` span); returns the kwargs shared by
+        in one ``jax.device_put`` (the ``raven.h2d`` span, whose ``arrays``
+        id counts what the call moved); returns the kwargs shared by
         ``CompiledPlan.run`` and ``run_async`` (plus bucket accounting)."""
         bucket = row_bucket(n, self.min_bucket)
-        with span("raven.h2d", group=group):
-            fact: dict[str, jnp.ndarray] = {}
-            for c in reg.scan_columns:
-                col = fact_np[c]
-                if len(col) < bucket:
-                    pad = np.zeros(bucket - len(col), dtype=col.dtype)
-                    col = np.concatenate([col, pad])
-                fact[c] = jnp.asarray(col)
-            row_valid = jnp.asarray(np.arange(bucket) < n)
+
+        def padded(col: np.ndarray) -> np.ndarray:
+            if len(col) < bucket:
+                pad = np.zeros(bucket - len(col), dtype=col.dtype)
+                col = np.concatenate([col, pad])
+            return col
+
+        n_arrays = len(reg.scan_columns) + 1 + (segments is not None)
+        with span("raven.h2d", group=group, arrays=n_arrays):
+            host = [padded(fact_np[c]) for c in reg.scan_columns]
+            host.append(np.arange(bucket) < n)
+            if segments is not None:
+                host.append(padded(segments[0]))
+            # every transfer starts before any completes; may_alias=False
+            # keeps each buffer a fresh copy the group alone owns, since the
+            # fact spine is donated and an unpadded column is the caller's
+            moved = jax.device_put(host, may_alias=False)
+        fact = dict(zip(reg.scan_columns, moved))
+        row_valid = moved[len(reg.scan_columns)]
         if segments is not None:
-            ids, k = segments
-            if len(ids) < bucket:
-                ids = np.concatenate(
-                    [ids, np.zeros(bucket - len(ids), dtype=np.int32)]
-                )
-            segments = (ids, k)
+            segments = (moved[-1], segments[1])
 
         # key on the *active* plan: a breaker-degraded registration serves
         # (and warms buckets for) its fallback's fingerprint
@@ -1451,29 +1468,29 @@ class PredictionQueryServer:
         pure (no request mutation), shared by the primary finish path and
         the shadow diff path. Waits for the device buffers first (the
         ``raven.device_wait`` span), so that the ``raven.d2h`` span holds
-        the copies alone."""
+        the one batched copy and the split."""
         gid = group[0].group
+        table = res.table
+        # only the segmented split of several requests reads the row ids
+        segmented = not (reg.sliceable or len(group) == 1 or reg.has_aggregate)
+        seg = res.seg if segmented else None
         with span("raven.device_wait", group=gid):
-            jax.block_until_ready((res.table.columns, res.table.valid, res.seg))
-        with span("raven.d2h", group=gid):
+            jax.block_until_ready((table.columns, table.valid, res.seg))
+        with span("raven.d2h", group=gid,
+                  arrays=len(table.columns) + 1 + segmented):
+            cols, valid, seg = _to_host(table.columns, table.valid, seg)
             if reg.sliceable:
-                cols = {
-                    k: np.asarray(v)[:n] for k, v in res.table.columns.items()
-                }
-                valid = np.asarray(res.table.valid)[:n]
-                return self._positional_results(group, cols, valid)
+                cols = {k: v[:n] for k, v in cols.items()}
+                return self._positional_results(group, cols, valid[:n])
             if len(group) == 1:
                 # a lone host-boundary/aggregate request: no splitting needed
-                return [res.table.to_numpy(compact=True)]
-            cols = {k: np.asarray(v) for k, v in res.table.columns.items()}
-            valid = np.asarray(res.table.valid)
+                return [{k: v[valid] for k, v in cols.items()}]
             if reg.has_aggregate:
                 # segmented fold: output row i belongs to request i
                 return [
                     {k: v[i:i + 1] for k, v in cols.items()}
                     for i in range(len(group))
                 ]
-            seg = np.asarray(res.seg)
             return [
                 {k: v[valid & (seg == i)] for k, v in cols.items()}
                 for i in range(len(group))
@@ -1509,11 +1526,13 @@ class PredictionQueryServer:
                 chunk = {c: v[off:off + rows] for c, v in cat.items()}
                 # the serial runner returns once the device is done
                 table = self._execute_padded(reg, chunk, rows, group=gid).table
-                with span("raven.d2h", group=gid):
-                    out_valid.append(np.asarray(table.valid)[:rows])
-                    for k, v in table.columns.items():
-                        out_cols.setdefault(k, []).append(np.asarray(v)[:rows])
-            with span("raven.d2h", group=gid):
+                with span("raven.d2h", group=gid,
+                          arrays=len(table.columns) + 1):
+                    cols, valid, _ = _to_host(table.columns, table.valid)
+                    out_valid.append(valid[:rows])
+                    for k, v in cols.items():
+                        out_cols.setdefault(k, []).append(v[:rows])
+            with span("raven.d2h", group=gid, arrays=0):
                 cols = {k: np.concatenate(v) for k, v in out_cols.items()}
                 valid = np.concatenate(out_valid)
                 self._positional_split(group, cols, valid)

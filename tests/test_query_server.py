@@ -2,6 +2,10 @@
 micro-batching (the cached hot path the paper's optimize-once model implies)."""
 from __future__ import annotations
 
+import time
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -15,7 +19,7 @@ from repro.relational.engine import (
     execute_plan,
     plan_fingerprint,
 )
-from repro.serve import PredictionQueryServer, row_bucket
+from repro.serve import PredictionQueryServer, query_server, row_bucket
 from repro.sql.parser import parse_prediction_query
 
 SQL_STAR = "SELECT * FROM PREDICT(model='m', data=patients) AS p WHERE score >= 0.6"
@@ -291,3 +295,145 @@ def test_server_chunks_oversized_batches(hospital, dt_query):
     ref = execute_plan(_optimize(dt_query, transform="sql"), tables).to_numpy()
     for k in ref:
         np.testing.assert_allclose(got[k], ref[k], rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Host <-> device copies: one batched call per direction per group
+# ---------------------------------------------------------------------------
+
+
+class _CountingJax:
+    """``jax`` as ``query_server`` sees it, counting the batched copies."""
+
+    def __init__(self):
+        self.gets: list[int] = []
+        self.puts: list[int] = []
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    def device_get(self, x):
+        self.gets.append(len(jax.tree_util.tree_leaves(x)))
+        return jax.device_get(x)
+
+    def device_put(self, x, *args, **kwargs):
+        self.puts.append(len(jax.tree_util.tree_leaves(x)))
+        return jax.device_put(x, *args, **kwargs)
+
+
+def _plan_answers(srv, reg, batches, mode):
+    """What the group's requests should get: the compiled plan's ``run`` on
+    the same padded batch, every column copied on its own, split on the
+    host the way each serving path promises."""
+    cat = {
+        c: np.concatenate([np.asarray(b[c]) for b in batches])
+        .astype(reg.fact_dtypes[c])
+        for c in reg.scan_columns
+    }
+    sizes = [len(next(iter(b.values()))) for b in batches]
+    n = sum(sizes)
+    seg_ids = np.repeat(np.arange(len(batches), dtype=np.int32), sizes)
+
+    def run(cols, rows, segmented):
+        bucket = row_bucket(rows, srv.min_bucket)
+
+        def pad(a):
+            return np.concatenate([a, np.zeros(bucket - rows, a.dtype)])
+
+        db = dict(reg.database)
+        db[reg.fact_table] = {c: jnp.asarray(pad(v)) for c, v in cols.items()}
+        res = reg.active.run(
+            database=db,
+            row_valid=jnp.asarray(np.arange(bucket) < rows),
+            params=reg.params if reg.param_names else None,
+            segments=(pad(seg_ids), len(batches)) if segmented else None,
+            bucketer=lambda m: row_bucket(m, srv.min_bucket),
+        )
+        out = {k: np.asarray(v) for k, v in res.table.columns.items()}
+        seg = None if res.seg is None else np.asarray(res.seg)
+        return out, np.asarray(res.table.valid), seg
+
+    def positional(cols, valid):
+        out, off = [], 0
+        for size in sizes:
+            m = valid[off:off + size]
+            out.append({k: v[off:off + size][m] for k, v in cols.items()})
+            off += size
+        return out
+
+    if mode == "chunked":
+        parts = []
+        for off in range(0, n, srv.max_bucket):
+            rows = min(srv.max_bucket, n - off)
+            chunk = {c: v[off:off + rows] for c, v in cat.items()}
+            cols, valid, _ = run(chunk, rows, False)
+            parts.append(({k: v[:rows] for k, v in cols.items()}, valid[:rows]))
+        cols = {k: np.concatenate([p[0][k] for p in parts]) for k in parts[0][0]}
+        return positional(cols, np.concatenate([p[1] for p in parts]))
+    cols, valid, seg = run(cat, n, mode in ("segmented", "aggregate"))
+    if mode in ("sliceable", "shadow"):
+        return positional({k: v[:n] for k, v in cols.items()}, valid[:n])
+    if mode == "lone":
+        return [{k: v[valid] for k, v in cols.items()}]
+    if mode == "aggregate":
+        return [{k: v[i:i + 1] for k, v in cols.items()}
+                for i in range(len(batches))]
+    return [{k: v[valid & (seg == i)] for k, v in cols.items()}
+            for i in range(len(batches))]
+
+
+# (mode, SELECT, transform, request sizes, groups or chunks the server runs)
+COPY_PATHS = {
+    "sliceable": (SQL_STAR, "sql", (50, 40, 30), 1),
+    "lone": (SQL_STAR, "none", (90,), 1),
+    "segmented": (SQL_STAR, "none", (70, 45), 1),
+    "aggregate": (SQL_AGG, "sql", (150, 90), 1),
+    "chunked": (SQL_STAR, "sql", (200,), 4),  # 64+64+64+8 rows
+    "shadow": (SQL_STAR, "sql", (60, 35), 2),  # the group and its mirror
+}
+
+
+@pytest.mark.parametrize("mode", list(COPY_PATHS))
+def test_each_group_copies_in_one_call_per_direction(
+    hospital, hospital_dt, monkeypatch, mode
+):
+    sql, transform, sizes, calls = COPY_PATHS[mode]
+    kw = {"min_bucket": 8, "max_bucket": 64} if mode == "chunked" else {}
+    srv = PredictionQueryServer(options=OptimizerOptions(transform=transform),
+                                **kw)
+    query = _query(hospital, hospital_dt, sql)
+    reg = srv.register("q", query, hospital.tables)
+    if mode == "shadow":
+        srv.stage_version("q", query.copy(), hospital.tables,
+                          version_label="v2")
+        srv.set_shadow("q", "v2")
+    batches = [_batch(n, seed=60 + i) for i, n in enumerate(sizes)]
+    want = _plan_answers(srv, reg, batches, mode)
+
+    counting = _CountingJax()
+    monkeypatch.setattr(query_server, "jax", counting)
+    reqs = [srv.submit("q", b) for b in batches]
+    srv.flush()
+    got = [r.wait(timeout=60) for r in reqs]
+    if mode == "shadow":
+        deadline = time.monotonic() + 60
+        while (srv.route_snapshot("q")["versions"]["v2"]["shadow_groups"] < 1
+               and time.monotonic() < deadline):
+            time.sleep(0.005)
+        v2 = srv.route_snapshot("q")["versions"]["v2"]
+        assert v2["shadow_groups"] == 1 and v2["shadow_errors"] == 0
+        assert v2["shadow_diff_rows"] == 0  # the mirror's split agrees bitwise
+    srv.shutdown()
+
+    assert len(counting.puts) == calls
+    assert len(counting.gets) == calls
+    # columns and validity, plus the row ids where requests share segments
+    seg_in = mode in ("segmented", "aggregate")
+    assert set(counting.puts) == {len(reg.scan_columns) + 1 + seg_in}
+    assert set(counting.gets) == {len(want[0]) + 1 + (mode == "segmented")}
+    for r, out, ref in zip(reqs, got, want):
+        assert r.done and r.error is None
+        assert sorted(out) == sorted(ref)
+        for k in ref:
+            assert out[k].dtype == ref[k].dtype
+            np.testing.assert_array_equal(out[k], ref[k], err_msg=k)

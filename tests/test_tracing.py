@@ -59,13 +59,14 @@ def _host_lines(log_dir: str) -> list[list[tuple]]:
     return lines
 
 
-def test_a_served_group_nests_its_steps_in_order_on_one_thread(served, tmp_path):
-    db, prep = served
+def _serve_traced(db, prep, log_dir: str, seeds) -> list:
+    """Serve one 100-row request per seed under the profiler; returns the
+    requests once every group has left its span."""
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
-    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
-        reqs = [prep.submit(_batch(100, seed=s)) for s in (2, 3)]
+        reqs = [prep.submit(_batch(100, seed=s)) for s in seeds]
         for r in reqs:
             r.wait(timeout=60)
         # answers are out before the scheduler leaves the group's span
@@ -75,24 +76,38 @@ def test_a_served_group_nests_its_steps_in_order_on_one_thread(served, tmp_path)
             time.sleep(0.001)
     finally:
         jax.profiler.stop_trace()
+    return reqs
+
+
+def _group_spans(log_dir: str) -> list[tuple[dict, list[tuple]]]:
+    """Each ``raven.group`` span's ids with the spans nested in it on its
+    thread, in order."""
+    out = []
+    for line in _host_lines(log_dir):
+        for name, a, b, stats in line:
+            if name == "raven.group":
+                inside = sorted((e for e in line if e[0] != "raven.group"
+                                 and a <= e[1] and e[2] <= b),
+                                key=lambda e: e[1])
+                out.append((stats, inside))
+    return out
+
+
+def test_a_served_group_nests_its_steps_in_order_on_one_thread(served, tmp_path):
+    db, prep = served
+    reqs = _serve_traced(db, prep, str(tmp_path), (2, 3))
 
     # the benchmark's reduction keeps every span
     names = {e[2] for e in trace.extract(str(tmp_path))}
     assert {"raven.submit", "raven.group", *GROUP_STEPS} <= names
 
-    groups = 0
-    for line in _host_lines(str(tmp_path)):
-        for name, a, b, stats in line:
-            if name != "raven.group":
-                continue
-            groups += 1
-            inside = sorted((e for e in line
-                             if e[0] in GROUP_STEPS and a <= e[1] and e[2] <= b),
-                            key=lambda e: e[1])
-            assert [e[0] for e in inside] == GROUP_STEPS
-            assert {e[3]["group"] for e in inside} == {stats["group"]}
-            assert stats["requests"] >= 1 and stats["rows"] >= 100
-    assert groups >= 1
+    groups = _group_spans(str(tmp_path))
+    for stats, inside in groups:
+        inside = [e for e in inside if e[0] in GROUP_STEPS]
+        assert [e[0] for e in inside] == GROUP_STEPS
+        assert {e[3]["group"] for e in inside} == {stats["group"]}
+        assert stats["requests"] >= 1 and stats["rows"] >= 100
+    assert len(groups) >= 1
     submits = [s for line in _host_lines(str(tmp_path))
                for name, _a, _b, s in line if name == "raven.submit"]
     assert sorted(s["rid"] for s in submits) == sorted(r.rid for r in reqs)
@@ -222,3 +237,20 @@ def test_union_and_idle_intervals():
     assert spans.union([(3, 5), (0, 2), (1, 3), (7, 8)]) == [(0, 5), (7, 8)]
     assert spans.idle_intervals([(1, 2), (4, 6)], 0, 10) == [(0, 1), (2, 4), (6, 10)]
     assert spans.idle_intervals([(0, 10)], 0, 10) == []
+
+
+def test_each_group_copies_once_each_way_and_counts_the_arrays(served, tmp_path):
+    db, prep = served
+    (reg,) = db.server.queries.values()
+    reqs = _serve_traced(db, prep, str(tmp_path), (7, 8, 9))
+    (result_columns,) = {len(r.result) for r in reqs}
+
+    groups = _group_spans(str(tmp_path))
+    assert groups
+    for _stats, inside in groups:
+        h2d = [e[3] for e in inside if e[0] == "raven.h2d"]
+        d2h = [e[3] for e in inside if e[0] == "raven.d2h"]
+        # one batched copy each way: every input column and the validity
+        # in, every result column and the validity out
+        assert [s["arrays"] for s in h2d] == [len(reg.scan_columns) + 1]
+        assert [s["arrays"] for s in d2h] == [result_columns + 1]
