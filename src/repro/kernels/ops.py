@@ -63,7 +63,16 @@ def pad_gemm_program(A, B, C, D, V, align: int = 128):
       * extra I columns: threshold +inf ⇒ decision 1, but their C rows are
         zero ⇒ P unchanged;
       * extra L columns: Dcount = -1 can never equal a non-negative path
-        count ⇒ match 0 ⇒ V never read (and V is 0 there anyway)."""
+        count ⇒ match 0 ⇒ V never read (and V is 0 there anyway).
+
+    Raises ``ValueError`` unless A is 0/1 with at most one 1 per column and C
+    is in {-1, 0, 1}: the kernel's one-pass contractions are exact only then
+    (``repro.kernels.tree_gemm``)."""
+    A, C = np.asarray(A), np.asarray(C)
+    if not (np.isin(A, (0, 1)).all() and (A.sum(axis=1) <= 1).all()):
+        raise ValueError("tree_gemm needs A 0/1 with at most one 1 per column")
+    if not np.isin(C, (-1, 0, 1)).all():
+        raise ValueError("tree_gemm needs C in {-1, 0, 1}")
     T, F, I = A.shape
     L = C.shape[2]
     Fp, Ip, Lp = _round_up(F, align), _round_up(I, align), _round_up(L, align)

@@ -17,10 +17,21 @@ block ``(1, 1, I|L)`` keeps its last two dimensions TPU-tileable. Callers
 size ``block_n`` with :func:`tree_gemm_block_n`, which budgets the step's
 VMEM (:func:`tree_gemm_vmem_bytes`) under ``repro.kernels.VMEM_BUDGET_BYTES``.
 
-Both contractions run at ``Precision.HIGHEST``: at the TPU's default
-precision an f32 matmul rounds its operands to bf16, which would round the
-features before the ``S <= B`` threshold compare and the leaf values before
-they are summed.
+Each contraction runs in the fewest bf16 MXU passes that keep it exact in
+float32, which holds only while the program keeps its contract
+(``ops.pad_gemm_program`` checks it):
+
+* ``A`` is 0/1 with at most one 1 per column, so each ``S[n, i]`` is a
+  single feature ``x[n, f]``. The wrapper splits ``x`` once, outside the
+  tree loop, into three bf16 pieces by truncation (:func:`split_bf16`);
+  their sum in any order is ``x`` bit for bit, so three one-pass dots
+  summed in f32 return the exact float32 feature for the ``S <= B``
+  compare.
+* ``C`` is in {-1, 0, 1} and the decisions are 0/1, so ``P = D·C`` sums
+  small integers: exact in one bf16 pass accumulated in f32.
+
+``A`` and ``C`` travel as bf16 (exact); ``B``, ``D`` and ``V`` stay f32, and
+the leaf values are summed in f32 in tree order.
 """
 from __future__ import annotations
 
@@ -33,20 +44,39 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
 
-_HI = jax.lax.Precision.HIGHEST
+
+def split_bf16(x: jnp.ndarray) -> jnp.ndarray:
+    """f32 ``x`` as three bf16 pieces, stacked ``(3, *x.shape)``, whose sum
+    in any order is ``x`` bit for bit, -0 included. Each piece truncates what
+    the ones before it left to its top 16 bits, so it keeps the remainder's
+    sign and no sum of pieces rounds. Exact for finite ``|x| >= 2**-102``
+    and zero; below that the lowest piece is subnormal, which the TPU
+    flushes to zero."""
+
+    def trunc(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000), jnp.float32)
+
+    x = x.astype(jnp.float32)
+    hi = trunc(x)
+    rest = jnp.copysign(x - hi, x)
+    mid = trunc(rest)
+    lo = jnp.copysign(rest - mid, rest)
+    return jnp.stack([hi, mid, lo]).astype(jnp.bfloat16)
 
 
 def tree_gemm_vmem_bytes(block_n: int, F: int, I: int, L: int) -> int:
     """VMEM one grid step holds: double-buffered input/output blocks (the
     ``(1, I|L)`` rows and the ``(block_n, 1)`` output occupy whole (8, 128)
-    tiles) plus the f32 temporaries S, D, P, match and match·V."""
+    tiles) plus the temporaries: the three pieces' f32 S and their sum, the
+    bf16 decisions, and the f32 P, match and match·V."""
     blocks = (
-        block_n * F + F * I + I * L  # x, A, C
-        + 8 * I + 2 * 8 * L  # B, D, V
-        + block_n * 128  # output column
+        2 * (3 * block_n * F + F * I + I * L)  # bf16 x pieces, A, C
+        + 4 * (8 * I + 2 * 8 * L)  # f32 B, D, V
+        + 4 * block_n * 128  # f32 output column
     )
-    temps = block_n * (2 * I + 3 * L)
-    return 4 * (2 * blocks + temps)
+    temps = block_n * (4 * 4 * I + 2 * I + 4 * 3 * L)
+    return 2 * blocks + temps
 
 
 def tree_gemm_block_n(n_rows: int, F: int, I: int, L: int) -> int:
@@ -60,6 +90,11 @@ def tree_gemm_block_n(n_rows: int, F: int, I: int, L: int) -> int:
     return bn
 
 
+def _dot(a, b):
+    """One bf16 MXU pass, accumulated in f32."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
 def _kernel(x_ref, a_ref, b_ref, c_ref, d_ref, v_ref, o_ref, *, base: float):
     t = pl.program_id(1)
 
@@ -67,13 +102,10 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, d_ref, v_ref, o_ref, *, base: float):
     def _init():
         o_ref[...] = jnp.full_like(o_ref, base)
 
-    s = jnp.dot(
-        x_ref[...], a_ref[0], precision=_HI, preferred_element_type=jnp.float32
-    )  # (BN, I) on the MXU
-    dec = (s <= b_ref[0]).astype(jnp.float32)  # (BN, I) vs (1, I)
-    p = jnp.dot(
-        dec, c_ref[0], precision=_HI, preferred_element_type=jnp.float32
-    )  # (BN, L) on the MXU
+    a = a_ref[0]
+    s = (_dot(x_ref[0], a) + _dot(x_ref[1], a)) + _dot(x_ref[2], a)  # (BN, I): x exactly
+    dec = (s <= b_ref[0]).astype(jnp.bfloat16)  # (BN, I) vs (1, I)
+    p = _dot(dec, c_ref[0])  # (BN, L): exact small integers
     match = (p == d_ref[0]).astype(jnp.float32)  # (BN, L); ≤ one leaf per row
     o_ref[...] += jnp.sum(match * v_ref[0], axis=1, keepdims=True)
 
@@ -91,7 +123,9 @@ def tree_gemm(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """x:(N,F) f32 (N % block_n == 0); A:(T,F,I); B:(T,I); C:(T,I,L);
-    D:(T,L); V:(T,L). Returns (N,) raw ensemble scores."""
+    D:(T,L); V:(T,L). Returns (N,) raw ensemble scores. ``x`` is split into
+    its bf16 pieces here, once, so every tree step of a row block reuses
+    them."""
     N, F = x.shape
     T, _, I = A.shape
     L = C.shape[2]
@@ -101,7 +135,7 @@ def tree_gemm(
         functools.partial(_kernel, base=float(base)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n, F), lambda n, t: (n, 0)),
+            pl.BlockSpec((3, block_n, F), lambda n, t: (0, n, 0)),
             pl.BlockSpec((1, F, I), lambda n, t: (t, 0, 0)),
             pl.BlockSpec((1, 1, I), lambda n, t: (t, 0, 0)),
             pl.BlockSpec((1, I, L), lambda n, t: (t, 0, 0)),
@@ -117,10 +151,10 @@ def tree_gemm(
         interpret=interpret,
         name="tree_gemm",
     )(
-        x.astype(jnp.float32),
-        A.astype(jnp.float32),
+        split_bf16(x),
+        A.astype(jnp.bfloat16),
         B.astype(jnp.float32).reshape(T, 1, I),
-        C.astype(jnp.float32),
+        C.astype(jnp.bfloat16),
         D.astype(jnp.float32).reshape(T, 1, L),
         V.astype(jnp.float32).reshape(T, 1, L),
     )

@@ -84,7 +84,10 @@ def test_tree_gemm_kernel_sweep(hospital, n_estimators, max_depth):
         Xj, jnp.asarray(A), jnp.asarray(B), jnp.asarray(C), jnp.asarray(D),
         jnp.asarray(V), base=prog.base, interpret=True,
     )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    if n_estimators == 1:  # one leaf value plus base: nothing to reorder
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
 @pytest.mark.kernel_parity
@@ -180,7 +183,126 @@ def test_tree_gemm_padding_is_inert(hospital):
             Xj, jnp.asarray(A), jnp.asarray(B), jnp.asarray(C),
             jnp.asarray(D), jnp.asarray(V), base=prog.base, interpret=True,
         )
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _truncate(v):
+    """``v`` with its float32 bit pattern cut to the top 16 bits."""
+    return (v.view(np.uint32) & np.uint32(0xFFFF0000)).view(np.float32)
+
+
+def test_split_bf16_pieces_are_exact_and_sum_to_x_bitwise():
+    """The kernel's split of ``x``: the bf16 pieces are the truncation
+    pieces computed here in float32 (so casting them lost nothing), and both
+    summation orders give ``x`` back bit for bit."""
+    from repro.kernels.tree_gemm import split_bf16
+
+    f32 = np.float32
+    big, tiny = np.finfo(f32).max, np.finfo(f32).tiny
+    edges = np.array([
+        0.0, -0.0, big, -big, tiny * 2**24, -tiny * 2**24,
+        np.nextafter(f32(2.0), f32(0)), np.nextafter(f32(2.0**-101), f32(0)),
+        np.nextafter(f32(1), f32(0)), np.nextafter(f32(1), f32(2)), -1.0,
+    ], f32)
+    rng = np.random.default_rng(18)
+    normals = rng.standard_normal(1 << 20).astype(f32)
+    # bit patterns over every exponent the split covers: 2**-102 to FLT_MAX
+    bits = rng.integers(0, 1 << 32, 1 << 20, dtype=np.uint64).astype(np.uint32)
+    exponent = (bits >> 23) & 0xFF
+    patterns = bits[(exponent >= 25) & (exponent < 255)].view(f32)
+    x = np.concatenate([edges, normals, patterns])
+
+    pieces = np.asarray(jax.jit(split_bf16)(jnp.asarray(x)))
+    assert pieces.dtype == jnp.bfloat16 and pieces.shape == (3, len(x))
+    hi, mid, lo = pieces.astype(f32)
+    want_hi = _truncate(x)
+    want_rest = np.copysign(x - want_hi, x)
+    want_mid = _truncate(want_rest)
+    want_lo = np.copysign(want_rest - want_mid, want_rest)
+    for got, want in ((hi, want_hi), (mid, want_mid), (lo, want_lo)):
+        np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    np.testing.assert_array_equal(((hi + mid) + lo).view(np.uint32), x.view(np.uint32))
+    np.testing.assert_array_equal(((hi + lo) + mid).view(np.uint32), x.view(np.uint32))
+
+
+def _full_tree_ensemble(rng, n_trees, depth, n_features):
+    """Full trees whose thresholds need all 24 bits of their mantissa."""
+    from repro.ml.trees import LEAF, TreeEnsemble
+
+    n_int, per = 2**depth - 1, 2**(depth + 1) - 1
+    k = np.arange(per)
+    base = (np.arange(n_trees) * per)[:, None]
+    feature = np.where(k < n_int, rng.integers(0, n_features, (n_trees, per)), LEAF)
+    threshold = rng.standard_normal((n_trees, per)).astype(np.float32)
+    threshold = (threshold.view(np.uint32) | np.uint32(1)).view(np.float32)
+    leaf = rng.standard_normal((n_trees, per)).astype(np.float32)
+    return TreeEnsemble(
+        feature=feature.reshape(-1), threshold=threshold.astype(np.float64).reshape(-1),
+        left=(base + np.minimum(2 * k + 1, per - 1)).reshape(-1),
+        right=(base + np.minimum(2 * k + 2, per - 1)).reshape(-1),
+        leaf_value=np.where(k < n_int, 0.0, leaf).reshape(-1),
+        tree_offsets=np.arange(n_trees + 1) * per, tree_weight=np.ones(n_trees),
+        base_score=0.375, post_transform="none", n_features=n_features,
+    )
+
+
+def _walk(ens, X):
+    """float32 scores, trees added in the kernel's order: base, tree 0, 1, …"""
+    out = np.full(len(X), np.float32(ens.base_score))
+    for r, row in enumerate(X):
+        for sl in ens.tree_slices():
+            node = sl.start
+            while ens.feature[node] >= 0:
+                go_left = row[ens.feature[node]] <= np.float32(ens.threshold[node])
+                node = ens.left[node] if go_left else ens.right[node]
+            out[r] = np.float32(out[r] + np.float32(ens.leaf_value[node]))
+    return out
+
+
+def test_tree_gemm_decides_on_the_exact_float32_feature():
+    """Rows at each threshold and one ulp either side of it: the kernel's
+    scores equal a float32 walk bit for bit, while features rounded to 16
+    bits (as ``Precision.HIGH`` does) route some of the same rows elsewhere."""
+    rng = np.random.default_rng(5)
+    ens = _full_tree_ensemble(rng, n_trees=4, depth=3, n_features=6)
+    split = np.flatnonzero(ens.feature >= 0)
+    X = np.repeat(rng.standard_normal((1, ens.n_features)).astype(np.float32),
+                  3 * len(split), axis=0)
+    for j, node in enumerate(split):
+        b = np.float32(ens.threshold[node])
+        X[3 * j:3 * j + 3, ens.feature[node]] = [
+            np.nextafter(b, np.float32(-np.inf)), b, np.nextafter(b, np.float32(np.inf))]
+    prog = build_gemm_program(ens)
+    A, B, C, D, V = ops.pad_gemm_program(prog.A, prog.B, prog.C, prog.Dcount, prog.V)
+    got = np.asarray(ops.tree_gemm_op(
+        jnp.asarray(X), *map(jnp.asarray, (A, B, C, D, V)), base=prog.base,
+        interpret=True,
+    ))
+    want = _walk(ens, X)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+    hi = X.astype(jnp.bfloat16).astype(np.float32)
+    rounded = hi + (X - hi).astype(jnp.bfloat16).astype(np.float32)
+    assert (_walk(ens, rounded) != want).any()
+
+
+@pytest.mark.parametrize("fault", ["two ones in a column of A", "A not 0/1",
+                                   "C outside {-1, 0, 1}"])
+def test_pad_gemm_program_refuses_what_the_kernel_cannot_run_exactly(fault):
+    T, F, I, L = 2, 4, 3, 4
+    A = np.zeros((T, F, I), np.float32)
+    A[:, 0, :] = 1
+    C = np.zeros((T, I, L), np.float32)
+    C[:, 0, :2], C[:, 0, 2:] = 1, -1
+    if fault == "two ones in a column of A":
+        A[1, 2, 1] = 1
+    elif fault == "A not 0/1":
+        A[0, 0, 2] = 0.5
+    else:
+        C[1, 2, 3] = 2
+    B, D, V = np.zeros((T, I)), np.zeros((T, L)), np.zeros((T, L))
+    with pytest.raises(ValueError, match="tree_gemm needs"):
+        ops.pad_gemm_program(A, B, C, D, V)
 
 
 @pytest.mark.parametrize(
@@ -196,6 +318,10 @@ def test_tree_gemm_block_n_fits_vmem_and_the_batch(N, F, I, L):
     assert bn & (bn - 1) == 0 and 8 <= bn <= 512
     assert bn <= max(8, 1 << (N - 1).bit_length())  # no padding past the bucket
     assert tree_gemm_vmem_bytes(bn, F, I, L) <= VMEM_BUDGET_BYTES
+    if F > 128:
+        # x travels as three double-buffered bf16 pieces: 1.5x one f32 block
+        grow = tree_gemm_vmem_bytes(bn, F, I, L) - tree_gemm_vmem_bytes(bn, 128, I, L)
+        assert grow >= 2 * 3 * 2 * bn * (F - 128) > 2 * 4 * bn * (F - 128)
 
 
 def test_tree_gemm_runtime_follows_use_pallas(monkeypatch):

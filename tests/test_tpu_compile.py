@@ -11,7 +11,8 @@ compiled program to hold the kernel (``tpu_custom_call``). Widths:
     20-tree depth-3 ensemble), pruned by the optimizer and at its full
     ~6.5k one-hot width;
   * hospital (9 numerics, 50 one-hot columns) and fig. 12's largest model,
-    500 trees at depth 8;
+    500 trees at depth 8; hospital's GB 300x6 at the bulk cell's 65,536 rows
+    and at an 8-row bucket, below bf16's 16-row tile;
   * a 16,384-row dimension table for the gather-join, and the largest one
     its VMEM budget admits; the segmented aggregate at 1 and 64 segments and
     at the most its VMEM budget admits.
@@ -94,6 +95,10 @@ TREE_GEMM_WIDTHS = {
     "flights-full": (4096, 20, 6528, 128, 128),
     # fig. 12: hospital (59 features), 500 trees at depth 8
     "fig12-500x8": (4096, 500, 128, 256, 256),
+    # the bulk cell's requests: hospital GB 300x6 at 65,536 rows
+    "hospital-bulk": (65_536, 300, 128, 128, 128),
+    # a bucket under bf16's 16-row tile, so the x pieces' block is 8 rows
+    "hospital-8-rows": (8, 300, 128, 128, 128),
 }
 
 
